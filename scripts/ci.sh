@@ -17,9 +17,9 @@ if [[ "${1:-}" == "--fast" ]]; then
     PYTEST_ARGS+=(-k "not subprocess and not DryRun and not TuneCLI and not collectives_counted")
 fi
 
-# Post-PR10 baseline: CI fails if the collected count ever drops below it
+# Collected-count floor: CI fails if the collected count ever drops below it
 # (a silently skipped/broken test file must not read as green).
-MIN_COLLECTED=684
+MIN_COLLECTED=703
 echo "=== check: collected test count >= ${MIN_COLLECTED} ==="
 COLLECT_OUT=$(python -m pytest -q --collect-only 2>&1 | tail -5 || true)
 COLLECTED=$(tail -1 <<<"$COLLECT_OUT" | grep -oE '^[0-9]+' || true)

@@ -93,7 +93,7 @@ _POW2_BLOCKS = (16, 32, 64, 128, 256, 512)
 
 # Shared launch knobs (ROADMAP PR-1 open item): every kernel space carries
 # the Mosaic grid dimension-semantics choice, threaded to every kernel's
-# ``pltpu.TPUCompilerParams`` and through the cost model's ``_dispatch_s``
+# ``pltpu.CompilerParams`` and through the cost model's ``_dispatch_s``
 # term, so ACTS tunes it jointly with the block sizes.  The GPU num_warps
 # occupancy hint is *plumbed* (every kernel and ``_dispatch_s`` accept
 # it) but joins a tune space only on backends whose lowering consumes it
@@ -378,8 +378,9 @@ def _pa_call(inputs, config, interpret):
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     maxg = k.shape[1] // T
-    k_pages = k.reshape(B * maxg, T, KV, D)
-    v_pages = v.reshape(B * maxg, T, KV, D)
+    # head-major pool: (groups, KV, T, D)
+    k_pages = k.reshape(B * maxg, T, KV, D).transpose(0, 2, 1, 3)
+    v_pages = v.reshape(B * maxg, T, KV, D).transpose(0, 2, 1, 3)
     pt = jnp.arange(B * maxg, dtype=jnp.int32).reshape(B, maxg)
     lengths = jnp.full((B,), kv_len, jnp.int32)
     return paged_flash_decode_pallas(

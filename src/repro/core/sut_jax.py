@@ -86,21 +86,30 @@ class JaxDryRunSUT:
         self.records = []  # full dry-run records of every test
 
     def test(self, config: Config) -> PerfMetric:
-        from repro.launch.dryrun import run_cell
-        from repro.launch.roofline import roofline_terms
-
-        knobs = knobs_from_config(config)
+        """Score a candidate the search generated: a config that fails to
+        compile (or a skipped cell) loses with ``inf`` instead of
+        crashing the tuner."""
         try:
-            rec = run_cell(self.arch, self.shape, multi_pod=self.multi_pod,
-                           knobs=knobs, verbose=False)
-        except Exception as e:  # invalid configs lose, but don't crash ACTS
+            return self.measure(config)
+        except Exception as e:
             if self.verbose:
                 print(f"[sut_jax] compile failed for {config}: {e}")
             return PerfMetric(value=math.inf, higher_is_better=False,
                               metrics={"error": str(e)})
+
+    def measure(self, config: Config) -> PerfMetric:
+        """Compile the cell under ``config`` and score it; a compile error
+        or a skipped cell raises.  The entry point for a config a user
+        asked for (``repro.launch.tune --probe``)."""
+        from repro.launch.dryrun import run_cell
+        from repro.launch.roofline import roofline_terms
+
+        knobs = knobs_from_config(config)
+        rec = run_cell(self.arch, self.shape, multi_pod=self.multi_pod,
+                       knobs=knobs, verbose=False)
         if rec.get("status") != "ok":
-            return PerfMetric(value=math.inf, higher_is_better=False,
-                              metrics={"error": rec.get("reason", "skipped")})
+            raise RuntimeError(f"cell {self.arch} x {self.shape} not run: "
+                               f"{rec.get('reason', 'skipped')}")
         terms = roofline_terms(rec)
         t = terms["t_est_s"]
         # HBM feasibility penalty on the resident estimate (exact argument

@@ -177,17 +177,13 @@ def _normalize_entries(entries: Sequence[Any]) -> Tuple[Any, ...]:
 class _SemanticSpec(PartitionSpec):
     """A PartitionSpec comparing by *meaning*, not entry spelling.
 
-    ``PartitionSpec`` is a plain tuple subclass, so ``P(("data",)) !=
-    P("data")`` even though they shard identically.  Specs produced by
-    ``spec_for_shape`` normalize single-axis tuples and ignore trailing
-    ``None`` entries on comparison, matching how ``NamedSharding``
-    interprets them.
+    ``PartitionSpec`` equality is positional, so ``P("data") !=
+    P("data", None)`` even though they shard identically.  Specs
+    produced by ``spec_for_shape`` normalize single-axis tuples and
+    ignore trailing ``None`` entries on comparison (and in their hash),
+    matching how ``NamedSharding`` interprets them.  Instances are built
+    by ``PartitionSpec``'s own constructor.
     """
-
-    def __new__(cls, *partitions):
-        # PartitionSpec.__new__ hard-codes its own class; rebuild here so
-        # subclass instances actually get the semantic comparison.
-        return tuple.__new__(cls, partitions)
 
     def __eq__(self, other) -> bool:
         if isinstance(other, (PartitionSpec, tuple)):

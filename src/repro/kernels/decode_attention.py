@@ -8,9 +8,13 @@ pre-allocated at max_seq).
 
 Layout: grid (B, KV-head, kv-blocks); all G query heads of a KV group are
 processed together as a (G, D) tile so each cache block is read ONCE per
-group (the GQA bandwidth win).  Online-softmax state (m, l, acc) lives in
-VMEM scratch across the kv-block dimension; fully-invalid blocks are
-skipped with ``pl.when`` (so a cache filled to 2k of 32k only streams 2k).
+group (the GQA bandwidth win).  The kernel streams a head-major
+(B, KV, S, D) view of the cache, so every block is a (block_kv, D) slab
+whose last two dims satisfy Mosaic's (8, 128)-or-full-dim rule at any
+head_dim; the wrapper transposes the caller's (B, S, KV, D) cache into
+that view.  Online-softmax state (m, l, acc) lives in 2-D VMEM scratch
+across the kv-block dimension; fully-invalid blocks are skipped with
+``pl.when`` (so a cache filled to 2k of 32k only streams 2k).
 """
 from __future__ import annotations
 
@@ -46,26 +50,26 @@ def _kernel(kvlen_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
     @pl.when(k_start < kv_len)  # skip never-written cache tail
     def compute():
-        q = q_ref[0, 0, :, :].astype(jnp.float32) * scale  # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bkv, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)  # (bkv, D)
+        q = q_ref[0, 0].astype(jnp.float32) * scale  # (G, D)
+        k = k_ref[0, 0].astype(jnp.float32)  # (bkv, D)
+        v = v_ref[0, 0].astype(jnp.float32)  # (bkv, D)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # (G,bkv)
         kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(kpos < kv_len, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
+        m_prev = m_ref[...]  # (G, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_ref[...] = l_ref[...] * alpha + p.sum(axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot(
+        p = jnp.exp(s - m_new)
+        l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
             p, v, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(ik == nk - 1)
     def finalize():
-        denom = jnp.maximum(l_ref[...], 1e-30)[:, None]
-        o_ref[0, 0, :, :] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        denom = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 def flash_decode_pallas(
@@ -91,6 +95,8 @@ def flash_decode_pallas(
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     nk = k.shape[1] // block_kv
     qg = q.reshape(B, KV, G, D)
+    k = k.transpose(0, 2, 1, 3)  # head-major (B, KV, S, D) view
+    v = v.transpose(0, 2, 1, 3)
     kv_len = jnp.asarray(kv_len, jnp.int32).reshape(1)
 
     # the kv-block dim carries the online-softmax scratch; B/KV parallel
@@ -104,14 +110,14 @@ def flash_decode_pallas(
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),  # kv_len scalar
             pl.BlockSpec((1, 1, G, D), lambda b, h, ik: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_kv, 1, D), lambda b, h, ik: (b, ik, h, 0)),
-            pl.BlockSpec((1, block_kv, 1, D), lambda b, h, ik: (b, ik, h, 0)),
+            pl.BlockSpec((1, 1, block_kv, D), lambda b, h, ik: (b, h, ik, 0)),
+            pl.BlockSpec((1, 1, block_kv, D), lambda b, h, ik: (b, h, ik, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, ik: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, D), jnp.float32),
         ],
         interpret=interpret,

@@ -10,6 +10,9 @@ TPU adaptation (vs. the CUDA flash-attention algorithm):
   loop — this lets Mosaic double-buffer the HBM→VMEM streams of K and V,
 * GQA is handled in the index maps (kv head = q head // group), so KV tiles
   are fetched once per group without materializing repeated heads,
+* the kernel runs on head-major (B, heads, S, D) views (the wrapper
+  transposes in and out), so every block is a (block, D) slab whose last
+  two dims meet Mosaic's (8, 128)-or-full-dim rule at any head_dim,
 * causal + sliding-window masking short-circuits fully-masked tiles with
   ``pl.when`` (block-level skip ≈ the CUDA early-exit) — causal attention
   does ~half the tile work of the full square.
@@ -60,9 +63,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
     @pl.when(reachable)
     def compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale  # (bq, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bkv, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)  # (bkv, D)
+        q = q_ref[0, 0].astype(jnp.float32) * scale  # (bq, D)
+        k = k_ref[0, 0].astype(jnp.float32)  # (bkv, D)
+        v = v_ref[0, 0].astype(jnp.float32)  # (bkv, D)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
@@ -73,19 +76,19 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         if window:
             mask &= kpos > qpos - window
         s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
+        m_prev = m_ref[...]  # (bq, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_ref[...] = l_ref[...] * alpha + p.sum(axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot(
+        p = jnp.exp(s - m_new)
+        l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
             p, v, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(ik == nk - 1)
     def finalize():
-        denom = jnp.maximum(l_ref[...], 1e-30)[:, None]
-        o_ref[0, :, 0, :] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        denom = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 def flash_attention_pallas(
@@ -119,6 +122,8 @@ def flash_attention_pallas(
         v = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
     nq = q.shape[1] // block_q
     nk = k.shape[1] // block_kv
+    # head-major views: (B, S, heads, D) -> (B, heads, S, D)
+    q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
 
     kernel = functools.partial(
         _kernel, block_q=block_q, block_kv=block_kv, seq_kv=Sk,
@@ -133,21 +138,21 @@ def flash_attention_pallas(
         grid=(B, H, nq, nk),
         **({"compiler_params": params} if params else {}),
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, D),
-                         lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, block_kv, 1, D),
-                         lambda b, h, iq, ik: (b, ik, h // group, 0)),
-            pl.BlockSpec((1, block_kv, 1, D),
-                         lambda b, h, iq, ik: (b, ik, h // group, 0)),
+            pl.BlockSpec((1, 1, block_q, D),
+                         lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, block_kv, D),
+                         lambda b, h, iq, ik: (b, h // group, ik, 0)),
+            pl.BlockSpec((1, 1, block_kv, D),
+                         lambda b, h, iq, ik: (b, h // group, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, D),
-                               lambda b, h, iq, ik: (b, iq, h, 0)),
+        out_specs=pl.BlockSpec((1, 1, block_q, D),
+                               lambda b, h, iq, ik: (b, h, iq, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),  # running max m
-            pltpu.VMEM((block_q,), jnp.float32),  # running denom l
+            pltpu.VMEM((block_q, 1), jnp.float32),  # running max m
+            pltpu.VMEM((block_q, 1), jnp.float32),  # running denom l
             pltpu.VMEM((block_q, D), jnp.float32),  # output accumulator
         ],
         interpret=interpret,
     )(q, k, v)
-    return out[:, :Sq]
+    return out.transpose(0, 2, 1, 3)[:, :Sq]

@@ -3,7 +3,7 @@
 Every kernel space carries two launch knobs (``repro.autotune.space``):
 
 * ``dim_semantics`` — "parallel" marks the embarrassingly-parallel outer
-  grid dims for Mosaic (``TPUCompilerParams.dimension_semantics``), which
+  grid dims for Mosaic (``CompilerParams.dimension_semantics``), which
   lets the two TPU cores split them (megacore); dims that carry VMEM
   scratch across steps (online-softmax/kv, GLA state) stay "arbitrary".
 * ``num_warps`` — the GPU-lowering occupancy hint.  Mosaic has no analog,
@@ -24,7 +24,7 @@ __all__ = ["launch_params"]
 
 def launch_params(dimension_semantics: Optional[str], n_grid_dims: int,
                   n_sequential: int, interpret: bool):
-    """``TPUCompilerParams`` for the launch knobs, or ``None``.
+    """``pltpu.CompilerParams`` for the launch knobs, or ``None``.
 
     ``n_sequential`` trailing grid dims are always "arbitrary" (they carry
     scratch state); the leading dims become "parallel" when requested.
@@ -34,4 +34,4 @@ def launch_params(dimension_semantics: Optional[str], n_grid_dims: int,
         return None
     sem = (("parallel",) * (n_grid_dims - n_sequential)
            + ("arbitrary",) * n_sequential)
-    return pltpu.TPUCompilerParams(dimension_semantics=sem)
+    return pltpu.CompilerParams(dimension_semantics=sem)

@@ -1,8 +1,11 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any jax import (jax locks the device
-# count at first init).  This module is the ONLY place the 512 placeholder
-# devices exist; smoke tests and benches see 1 device.
+# The lines above MUST run before any jax import (jax locks the platform
+# and device count at first init).  The dry-run is a CPU analysis of a
+# 512-device mesh: it never takes an accelerator, even on a host that has
+# one.  This module is the ONLY place the 512 placeholder devices exist;
+# smoke tests and benches see 1 device.
 """Multi-pod dry-run (deliverable e).
 
 For every (architecture × input shape × mesh) cell:
@@ -43,6 +46,7 @@ from repro.configs import (
 )
 from repro.data import batch_specs
 from repro.dist.sharding import axis_rules, spec_for_shape
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import describe_mesh, make_production_mesh
 from repro.models import Model
 from repro.models.common import abstract_params, param_specs
@@ -250,6 +254,7 @@ def main(argv=None) -> int:
     ap.add_argument("--tag", default="baseline")
     ap.add_argument("--force", action="store_true")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     knob_kwargs: Dict[str, Any] = {}
     for kv in args.knob:

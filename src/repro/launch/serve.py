@@ -59,6 +59,7 @@ import jax
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.serve import ServeConfig, ServeEngine
 from repro.serve.scheduler import PAGE_POLICIES, SCHEDULES, TP_MODES
@@ -123,6 +124,7 @@ def main(argv=None) -> int:
                          "shifts to short-tail shared-prefix prompts, so "
                          "--retune has a drift to catch")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = reduced(get_config(args.arch))
     model = Model(cfg)

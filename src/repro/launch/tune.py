@@ -1,6 +1,9 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
-# Must precede any jax import: the tuner compiles against the production mesh.
+# Must precede any jax import: the tuner is a CPU analysis that compiles
+# against the 512-device production mesh, so it never takes an
+# accelerator, even on a host that has one.
 """ACTS over the JAX runtime (the paper's technique applied to this system).
 
 Four modes:
@@ -42,8 +45,10 @@ import argparse
 import json
 import sys
 import time
+import traceback
 
 from repro.configs import ARCH_IDS, SHAPES
+from repro.launch.compile_cache import enable_compile_cache
 
 __all__ = ["main"]
 
@@ -219,6 +224,7 @@ def main(argv=None) -> int:
     ap.add_argument("--kernel-budget", type=int, default=16)
     ap.add_argument("--out-dir", default="results/tune")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.optimizer is None:
         args.optimizer = "subspace_rr" if args.joint else "rrs"
     if args.real and not args.joint:
@@ -275,7 +281,15 @@ def main(argv=None) -> int:
                 config[k] = _parse_value(v)
         space.validate(config)
         t0 = time.time()
-        metric = sut.test(config)
+        try:
+            # the config the user asked for: a compile failure is the
+            # answer, so it surfaces (the search path scores it inf)
+            metric = sut.measure(config)
+        except Exception:
+            traceback.print_exc()
+            print(f"probe failed: {args.arch} x {args.shape} under "
+                  f"{config}", file=sys.stderr)
+            return 1
         print(json.dumps({
             "arch": args.arch, "shape": args.shape,
             "config": {k: config[k] for k in sorted(config)},

@@ -358,7 +358,7 @@ def self_attention(
     writes for sliding-window blocks) and attention runs against the buffer.
     A (B,)-vector ``cache_index`` is the continuous-batching decode path:
     every slot appends its single token at its *own* position.  With
-    ``page_table`` the cache is a (groups, group_tokens, KV, D) pool and
+    ``page_table`` the cache is a (groups, KV, group_tokens, D) pool and
     the append/attend go through the table (``repro.serve.paging``).
     """
     q, k, v = _project_qkv(params, x, None, cfg)
@@ -372,16 +372,16 @@ def self_attention(
     new_cache = None
     if cache is not None and page_table is not None:
         B = x.shape[0]
-        T = cache["k"].shape[1]
+        T = cache["k"].shape[2]
         S_new = k.shape[1]
         pos = jnp.asarray(cache_index, jnp.int32).reshape(B)
         if S_new == 1:
             # Paged pool: single-token decode append through the table.
             gid = page_table[jnp.arange(B), pos // T]
             off = pos % T
-            ck = cache["k"].at[gid, off].set(
+            ck = cache["k"].at[gid, :, off].set(
                 k[:, 0].astype(cache["k"].dtype))
-            cv = cache["v"].at[gid, off].set(
+            cv = cache["v"].at[gid, :, off].set(
                 v[:, 0].astype(cache["v"].dtype))
             new_cache = {"k": ck, "v": cv}
             y = _paged_decode_attend(q, ck, cv, page_table, pos + 1)
@@ -403,9 +403,9 @@ def self_attention(
                            jnp.minimum(lg, MAXG - 1)],
                 G_pool)
             off = ppos % T
-            ck = cache["k"].at[gid, off].set(
+            ck = cache["k"].at[gid, :, off].set(
                 k.astype(cache["k"].dtype), mode="drop")
-            cv = cache["v"].at[gid, off].set(
+            cv = cache["v"].at[gid, :, off].set(
                 v.astype(cache["v"].dtype), mode="drop")
             new_cache = {"k": ck, "v": cv}
             y = _paged_verify_attend(q, ck, cv, page_table, pos)
@@ -472,15 +472,46 @@ def _paged_decode_attend(q, k_pages, v_pages, page_table, lengths):
     from their physical groups); on CPU the pure-jnp gather reference —
     interpret-mode Pallas times the Python emulator, not the hardware,
     exactly like the other kernel entry points."""
-    from repro.kernels.ops import default_interpret, paged_flash_decode
+    from repro.kernels.ops import default_interpret
     from repro.kernels.paged_attention import paged_attention_ref
 
     qs = q[:, 0]
     if default_interpret():
         out = paged_attention_ref(qs, k_pages, v_pages, page_table, lengths)
     else:
-        out = paged_flash_decode(qs, k_pages, v_pages, page_table, lengths)
+        out = _paged_kernel_per_shard(qs, k_pages, v_pages, page_table,
+                                      lengths)
     return out[:, None].astype(v_pages.dtype)
+
+
+def _paged_kernel_per_shard(q, k_pages, v_pages, page_table, lengths):
+    """The Pallas paged kernel, run per shard under an active mesh.
+
+    GSPMD cannot partition a Mosaic kernel, so under ``axis_rules`` with
+    a mesh the call runs inside ``shard_map``: slots split over the
+    batch rule's mesh axis, and KV heads (with their query-head groups,
+    which stay contiguous per shard) over the axis the pool's ``kv_heads``
+    dim is split on (``POOL_AXES``) — or not at all where it replicates.
+    Without a mesh it is the plain kernel call."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.dist.sharding import current_rules, spec_for_shape
+    from repro.kernels.ops import paged_flash_decode
+    from repro.kernels.paged_attention import POOL_AXES
+
+    active = current_rules()
+    if active is None or active[1] is None:
+        return paged_flash_decode(q, k_pages, v_pages, page_table, lengths)
+    rules, mesh = active
+    pool = spec_for_shape(k_pages.shape, POOL_AXES, rules, mesh)
+    heads = pool[1] if len(pool) > 1 else None
+    batch = spec_for_shape(lengths.shape, ("batch",), rules, mesh)
+    rows = batch[0] if len(batch) else None
+    per_shard = jax.shard_map(
+        paged_flash_decode, mesh=mesh,
+        in_specs=(P(rows, heads), P(*pool), P(*pool), P(rows), P(rows)),
+        out_specs=P(rows, heads), check_vma=False)
+    return per_shard(q, k_pages, v_pages, page_table, lengths)
 
 
 def _paged_verify_attend(q, k_pages, v_pages, page_table, base):
@@ -493,10 +524,12 @@ def _paged_verify_attend(q, k_pages, v_pages, page_table, base):
     rejected-tail writes are masked out the same way stale pool tokens
     are in the single-token ref, so the accepted prefix attends exactly
     the KV a draft-free run would."""
+    from repro.kernels.paged_attention import gather_pages
+
     B, C, H, D = q.shape
-    G_pool, T, KV, _ = k_pages.shape
-    k = k_pages[page_table].reshape(B, -1, KV, D).astype(jnp.float32)
-    v = v_pages[page_table].reshape(B, -1, KV, D).astype(jnp.float32)
+    k = gather_pages(k_pages, page_table).astype(jnp.float32)
+    v = gather_pages(v_pages, page_table).astype(jnp.float32)
+    KV = k.shape[2]
     Gq = H // KV
     qg = q.reshape(B, C, KV, Gq, D).astype(jnp.float32) / math.sqrt(D)
     s = jnp.einsum("bckgd,bskd->bkgcs", qg, k)

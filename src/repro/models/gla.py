@@ -13,8 +13,10 @@ matmuls hit the MXU, the scan is O(S/chunk).  This file is the pure-jnp
 reference; ``repro.kernels`` provides the Pallas TPU kernel for the same
 computation.
 
-All math is f32 internally; decays are computed as differences before
-exponentiation so nothing overflows.
+All math is f32 internally; decays are exponentiated only as sums of
+log-decays over the steps they span (never a product of exponentials, so
+nothing overflows, and never a difference of prefix sums, so nothing
+cancels).
 """
 from __future__ import annotations
 
@@ -58,18 +60,24 @@ def chunked_gla(
     def one_chunk(state, inputs):
         qb, kb, vb, gb = inputs  # (B,L,H,·)
         c = jnp.cumsum(gb, axis=1)  # inclusive cumsum (B,L,H)
+        # segment sums seg[t, s] = Σ_{s<u≤t} g_u = c_t − c_s, summed over
+        # their own steps: as a difference of two prefix sums they would
+        # cancel catastrophically once |c| grows (mamba2 log-decays reach
+        # -100 per step, so c reaches -1e3 within a chunk)
+        tri = jnp.tril(jnp.ones((L, L), bool))
+        strict = jnp.tril(jnp.ones((L, L), bool), -1)  # s < t
+        seg = jnp.cumsum(jnp.where(strict[None, :, :, None],
+                                   gb[:, :, None, :], 0.0), axis=1)
         # inter-chunk: y += exp(c_t) · qᵀ S_in
         y_inter = jnp.einsum("blhk,bhkv->blhv", qb * jnp.exp(c)[..., None], state)
         # intra-chunk: decay matrix exp(c_t − c_s), s ≤ t
-        dmat = c[:, :, None, :] - c[:, None, :, :]  # (B, t, s, H)
-        tri = jnp.tril(jnp.ones((L, L), bool))
-        dmat = jnp.where(tri[None, :, :, None], dmat, -jnp.inf)
+        dmat = jnp.where(tri[None, :, :, None], seg, -jnp.inf)  # (B,t,s,H)
         att = jnp.einsum("blhk,bmhk->blmh", qb, kb) * jnp.exp(dmat)
         y_intra = jnp.einsum("blmh,bmhv->blhv", att, vb)
         # state out: S = exp(c_L) S_in + Σ_s exp(c_L − c_s) k_s v_sᵀ
         cL = c[:, -1, :]  # (B,H)
         carry_decay = jnp.exp(cL)[:, :, None, None]
-        k_decay = jnp.exp(cL[:, None, :] - c)  # (B,L,H)
+        k_decay = jnp.exp(seg[:, -1])  # (B,L,H): c_L − c_s per step s
         state_new = carry_decay * state + jnp.einsum(
             "blhk,blhv->bhkv", kb * k_decay[..., None], vb)
         return state_new, y_inter + y_intra

@@ -176,9 +176,9 @@ def paged_cache_block_defs(cfg: ModelConfig, kind: str, n_groups: int,
         # kv_heads axis may shard (model-axis TP); groups stay whole so
         # the page-table index_map addresses every shard identically.
         return {
-            "k": ParamDef((n_groups, group_tokens, KV, Dh), POOL_AXES,
+            "k": ParamDef((n_groups, KV, group_tokens, Dh), POOL_AXES,
                           zeros_init(), dt),
-            "v": ParamDef((n_groups, group_tokens, KV, Dh), POOL_AXES,
+            "v": ParamDef((n_groups, KV, group_tokens, Dh), POOL_AXES,
                           zeros_init(), dt),
         }
     if kind == "cross":
@@ -686,7 +686,8 @@ class Model:
 
     def init_paged_cache(self, n_groups: int, group_tokens: int):
         """KV pools for the paged layout: ``{"blocks": ...}`` with
-        (n_groups, group_tokens, KV, D) pools per attention block.  The
+        head-major (n_groups, KV, group_tokens, D) pools per attention
+        block (the paged kernel's block layout).  The
         page table and per-slot lengths live with the engine — group 0 is
         the allocator's scratch group (idle decode lanes write there)."""
         return init_params(paged_cache_defs(self.cfg, n_groups,
@@ -783,9 +784,10 @@ class Model:
         C = batch["tokens"].shape[1]
 
         def gather(l):
-            g = l[:, page_row]  # (n_sb, MAXG, T, KV, D)
-            n_sb, maxg, T = g.shape[:3]
-            return g.reshape(n_sb, 1, maxg * T, *g.shape[3:])
+            g = l[:, page_row]  # (n_sb, MAXG, KV, T, D)
+            n_sb, maxg, KV, T, D = g.shape
+            return g.transpose(0, 1, 3, 2, 4).reshape(n_sb, 1, maxg * T,
+                                                      KV, D)
 
         view = {"blocks": jax.tree_util.tree_map(gather, cache["blocks"]),
                 "index": length}
@@ -797,10 +799,12 @@ class Model:
         pos = length + jnp.arange(C)
 
         def scatter(l, nv):
-            T = l.shape[2]
+            T = l.shape[3]
+            # (n_sb, C, KV, D) -> (C, n_sb, KV, D): the two index arrays
+            # are split by a slice, so the indexed dims lead the update
             seg = jax.lax.dynamic_slice_in_dim(nv, length, C, axis=2)[:, 0]
-            return l.at[:, page_row[pos // T], pos % T].set(
-                seg.astype(l.dtype))
+            return l.at[:, page_row[pos // T], :, pos % T].set(
+                seg.transpose(1, 0, 2, 3).astype(l.dtype))
 
         new_cache = dict(cache, blocks=jax.tree_util.tree_map(
             scatter, cache["blocks"], new_view["blocks"]))

@@ -439,9 +439,8 @@ class ServeEngine:
         """device_put a pytree onto the mesh with per-leaf NamedShardings.
 
         ``specs`` mirrors ``tree`` with a PartitionSpec at every array
-        position; since PartitionSpec is itself a tuple the spec tree is
-        flattened only UP TO the data tree's structure (never into the
-        specs themselves)."""
+        position; the spec tree is flattened only UP TO the data tree's
+        structure, so each spec stays one leaf whatever its type."""
         flat, treedef = jax.tree_util.tree_flatten(tree)
         sflat = treedef.flatten_up_to(specs)
         put = [jax.device_put(x, NamedSharding(self.mesh,
@@ -818,7 +817,7 @@ class ServeEngine:
 
     def _copy_group_blocks(self, blocks, src, dst):
         """Device copy of one physical pool group (the CoW split): every
-        paged cache leaf is (n_sub, G, T, KV, D) — copy pool row
+        paged cache leaf is (n_sub, G, KV, T, D) — copy pool row
         ``src`` into ``dst`` across all blocks."""
         return jax.tree_util.tree_map(
             lambda l: l.at[:, dst].set(l[:, src]), blocks)

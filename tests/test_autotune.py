@@ -123,6 +123,39 @@ class TestKernelSUTTiming:
         assert m.value > 0 and not m.higher_is_better
         assert m.metrics["mode"] == "time"
 
+    @pytest.mark.parametrize("kernel,dims,config", [
+        ("rmsnorm", {"ROWS": 64, "D": 32}, {"block_rows": 16}),
+        ("paged_attention", {"B": 2, "S": 64, "H": 4, "KV": 2, "D": 16},
+         {"pages_per_block": 2}),
+    ])
+    def test_timed_runs_do_not_compile(self, kernel, dims, config):
+        """The timed repeats run one compiled program: compiles per
+        ``test`` do not grow with ``timing_iters`` (an eager kernel call
+        compiles its Pallas kernel again on every call)."""
+        import jax
+
+        compiles = []
+
+        def count(event, duration, **_):
+            if event == "/jax/core/compile/backend_compile_duration":
+                compiles.append(event)
+
+        # warm the eager helper ops (input draws, index arrays) first
+        KernelSUT(kernel, dims, mode="time", interpret=True,
+                  timing_iters=1).test(config)
+        jax.monitoring.register_event_duration_secs_listener(count)
+        try:
+            per_test = []
+            for iters in (1, 4):
+                sut = KernelSUT(kernel, dims, mode="time", interpret=True,
+                                timing_iters=iters)
+                before = len(compiles)
+                sut.test(config)
+                per_test.append(len(compiles) - before)
+        finally:
+            jax.monitoring.unregister_event_duration_listener(count)
+        assert per_test[0] == per_test[1], per_test
+
 
 class TestKernelThreading:
     """Block overrides flow from the cache through the public entry points."""

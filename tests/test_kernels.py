@@ -209,8 +209,8 @@ class TestPagedDecode:
 
     def _pool(self, rng, B, maxg, T, KV, D, dtype, extra=3):
         G = B * maxg + extra  # a few unused groups (incl. scratch-like 0)
-        kp = _rand(rng, (G, T, KV, D), dtype)
-        vp = _rand(rng, (G, T, KV, D), dtype)
+        kp = _rand(rng, (G, KV, T, D), dtype)
+        vp = _rand(rng, (G, KV, T, D), dtype)
         # random non-identity table over groups 1..G-1, unique per entry
         perm = 1 + rng.permutation(G - 1)[:B * maxg]
         pt = jnp.asarray(perm.reshape(B, maxg), jnp.int32)
@@ -223,7 +223,8 @@ class TestPagedDecode:
         (3, 4, 1, 32, 16, 4),   # MQA
     ])
     def test_against_refs(self, dtype, B, H, KV, D, T, maxg):
-        from repro.kernels.paged_attention import (paged_attention_ref,
+        from repro.kernels.paged_attention import (gather_pages,
+                                                   paged_attention_ref,
                                                    paged_flash_decode_pallas)
 
         rng = np.random.default_rng(hash((B, H, KV, D, T)) % 2**31)
@@ -238,8 +239,8 @@ class TestPagedDecode:
             np.asarray(out, np.float32), np.asarray(ref, np.float32),
             **TOL[dtype])
         # gather-to-dense oracle per sequence
-        kd = kp[pt].reshape(B, maxg * T, KV, D)
-        vd = vp[pt].reshape(B, maxg * T, KV, D)
+        kd = gather_pages(kp, pt)
+        vd = gather_pages(vp, pt)
         for b in range(B):
             L = int(lengths[b])
             dense = attention_ref(q[b:b + 1, None], kd[b:b + 1, :L],
@@ -263,13 +264,14 @@ class TestPagedDecode:
         for seed in (0, 1):
             prm = 1 + np.random.default_rng(seed).permutation(B * maxg)
             G = B * maxg + 2
-            kp = np.zeros((G, T, KV, D), np.float32)
-            vp = np.zeros((G, T, KV, D), np.float32)
+            kp = np.zeros((G, KV, T, D), np.float32)
+            vp = np.zeros((G, KV, T, D), np.float32)
             pt = prm.reshape(B, maxg)
             for b in range(B):
                 for g in range(maxg):
-                    kp[pt[b, g]] = np.asarray(logical_k)[b, g * T:(g + 1) * T]
-                    vp[pt[b, g]] = np.asarray(logical_v)[b, g * T:(g + 1) * T]
+                    sl = slice(g * T, (g + 1) * T)
+                    kp[pt[b, g]] = np.asarray(logical_k)[b, sl].swapaxes(0, 1)
+                    vp[pt[b, g]] = np.asarray(logical_v)[b, sl].swapaxes(0, 1)
             outs.append(np.asarray(paged_flash_decode_pallas(
                 q, jnp.asarray(kp), jnp.asarray(vp),
                 jnp.asarray(pt, jnp.int32), lengths, interpret=True)))

@@ -1,4 +1,5 @@
 """Unit tests for the logical-axis sharding layer + HLO cost analyzer."""
+import os
 import subprocess
 import sys
 import textwrap
@@ -18,6 +19,9 @@ from repro.dist.sharding import (
     constrain,
     spec_for_shape,
 )
+
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 class FakeMesh:
@@ -136,9 +140,9 @@ class TestServePresets:
         scalar-prefetched page table indexes it), kv_heads split."""
         from repro.kernels.paged_attention import POOL_AXES
         mesh = FakeMesh(data=1, model=2)
-        spec = spec_for_shape((8, 64, 4, 16), POOL_AXES,
+        spec = spec_for_shape((8, 4, 64, 16), POOL_AXES,
                               RULE_PRESETS["serve_tp"], mesh)
-        assert spec == P(None, None, "model")
+        assert spec == P(None, "model")
 
 
 class TestHloCostAnalyzer:
@@ -254,3 +258,64 @@ class TestTuneCLI:
         assert blob["value_s"] > 0
         assert blob["metrics"]["dominant"] in ("compute", "memory",
                                                "collective")
+
+    def test_probe_failure_exits_nonzero(self):
+        """A probe of a config that fails to compile is an error the user
+        sees: non-zero exit and the error text, not a silent ``inf``."""
+        script = textwrap.dedent("""
+            import sys
+            import repro.launch.dryrun as dryrun
+
+            def boom(*a, **k):
+                raise RuntimeError("planted compile failure")
+
+            dryrun.run_cell = boom
+            from repro.launch.tune import main
+            sys.exit(main(["--arch", "seamless-m4t-medium",
+                           "--shape", "decode_32k", "--probe", ""]))
+        """)
+        out = subprocess.run(
+            [sys.executable, "-c", script], cwd=REPO_ROOT,
+            capture_output=True, text=True, timeout=240,
+            env={**os.environ, "PYTHONPATH": "src"})
+        assert out.returncode == 1, out.stderr[-2000:]
+        assert "planted compile failure" in out.stderr
+        assert "value_s" not in out.stdout
+
+
+class TestDryRunSUT:
+    """``JaxDryRunSUT``: search candidates lose with ``inf``; a measured
+    config's compile error propagates."""
+
+    def test_search_scores_inf_measure_raises(self, monkeypatch):
+        import types
+
+        from repro.core.sut_jax import JaxDryRunSUT, knob_space
+
+        def boom(*a, **k):
+            raise RuntimeError("planted compile failure")
+
+        monkeypatch.setitem(sys.modules, "repro.launch.dryrun",
+                            types.SimpleNamespace(run_cell=boom))
+        sut = JaxDryRunSUT("seamless-m4t-medium", "decode_32k")
+        config = knob_space("decode").default_config()
+        metric = sut.test(config)
+        assert metric.value == float("inf")
+        assert "planted compile failure" in metric.metrics["error"]
+        with pytest.raises(RuntimeError, match="planted compile failure"):
+            sut.measure(config)
+
+    def test_skipped_cell_raises_on_measure(self, monkeypatch):
+        import types
+
+        from repro.core.sut_jax import JaxDryRunSUT, knob_space
+
+        monkeypatch.setitem(
+            sys.modules, "repro.launch.dryrun",
+            types.SimpleNamespace(run_cell=lambda *a, **k: {
+                "status": "skipped", "reason": "not applicable"}))
+        sut = JaxDryRunSUT("gemma-7b", "long_500k")
+        config = knob_space("decode").default_config()
+        assert sut.test(config).value == float("inf")
+        with pytest.raises(RuntimeError, match="not applicable"):
+            sut.measure(config)

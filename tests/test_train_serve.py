@@ -193,10 +193,28 @@ class TestChunkedPrefill:
         assert rc.prefill_chunks == expect  # the knob demonstrably acts
         assert rw.prefill_chunks == 1
 
+    # Whole-prompt prefill attends over the prompt's S keys; the chunked
+    # path attends over the whole max_seq cache buffer with a length mask.
+    # Same math, but float32 softmax/value reductions of different length
+    # round differently (~1-3 ulp of the output's magnitude).  Layer 0's
+    # K/V are bit-identical; from layer 1 on, that rounding is carried
+    # through the residual stream, the MLP and the next projections.  So
+    # the bound is a few tens of float32 ulps of each leaf's largest
+    # magnitude, not an elementwise rtol (near-zero entries carry the
+    # same absolute error as large ones).
+    PARITY_ULPS = 32
+
+    @classmethod
+    def _assert_close_ulps(cls, a, b):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        atol = cls.PARITY_ULPS * np.finfo(np.float32).eps * np.abs(a).max()
+        np.testing.assert_allclose(a, b, rtol=0, atol=atol)
+
     @pytest.mark.parametrize("plen,chunk", PAIRS)
     def test_kv_cache_parity(self, engine, plen, chunk):
-        """Chunked and whole-prompt prefill leave identical KV caches and
-        last-token logits behind."""
+        """Chunked and whole-prompt prefill leave the same KV caches and
+        last-token logits behind, to float32 rounding of the attention
+        reductions (layer 0's K/V bit-identical)."""
         model, params = engine
         tok = jnp.asarray(self._prompts(plen), jnp.int32)
         lg_w, cache_w = model.prefill(params, {"tokens": tok},
@@ -206,11 +224,14 @@ class TestChunkedPrefill:
             lg_c, cache_c = model.prefill_chunk(
                 params, {"tokens": tok[:, s:s + chunk]}, cache_c)
         assert int(cache_w["index"]) == int(cache_c["index"]) == plen
-        np.testing.assert_allclose(np.asarray(lg_w), np.asarray(lg_c),
-                                   rtol=1e-5, atol=1e-6)
+        self._assert_close_ulps(lg_w, lg_c)
+        jax.tree_util.tree_map(self._assert_close_ulps,
+                               cache_w["blocks"], cache_c["blocks"])
+        # the first layer's K/V come from identical projections of
+        # identical embeddings: no reduction of different length yet
         jax.tree_util.tree_map(
-            lambda a, b: np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6),
+            lambda a, b: np.testing.assert_array_equal(
+                np.asarray(a[0]), np.asarray(b[0])),
             cache_w["blocks"], cache_c["blocks"])
 
     def test_decode_continues_from_chunked_cache(self, engine):
