@@ -47,6 +47,7 @@ from .paging import (PAGE_TOKENS, OversubscriptionError, PageAllocator,
                      PrefixIndex, min_pages_for)
 from .scheduler import (PAGE_POLICIES, SCHEDULES, TP_MODES, Request,
                         SlotScheduler)
+from .spans import ACCEPT, ADMIT, DISPATCH, GENERATE, PREFILL, READBACK, span
 
 __all__ = ["ServeConfig", "ServeEngine", "GenerationResult",
            "OversubscriptionError", "TP_MODES"]
@@ -286,8 +287,10 @@ class GenerationResult:
     # observable evidence the prefill_chunk knob acts
     prefill_chunks: int = 0
     # per-request runtime provenance (rid order == input order):
-    # {"rid", "prompt_len", "new_tokens", "latency_s", "ttft_s",
-    #  "preemptions"}
+    # {"rid", "prompt_len", "new_tokens", "admit_s", "latency_s",
+    #  "ttft_s", "preemptions"}; the times are seconds from the call's
+    # start: first admission to a slot (a preempted request keeps it),
+    # first token, last token
     per_request: List[Dict[str, Any]] = field(default_factory=list)
     # recompute preemptions issued (on_demand page policy only): each one
     # re-queued a request whose re-prefill cost is the price of admitting
@@ -307,6 +310,10 @@ class GenerationResult:
     # "n_tests", "warm_source", "spec_accept", "measured_accept",
     # "applied": {knob: (old, new)}}
     retunes: List[Dict[str, Any]] = field(default_factory=list)
+    # decode steps before which admission stopped with a free slot and a
+    # pending request because the pool could not hold the head's
+    # reservation (paged runtime; 0 where the pool never refused)
+    admission_blocked: int = 0
 
     @property
     def acceptance_rate(self) -> float:
@@ -324,7 +331,11 @@ class GenerationResult:
 
     @property
     def decode_tokens_per_sec(self) -> float:
-        n = sum(len(t) for t in self.tokens)
+        """Tokens of decode steps per decode second.  The first token of
+        every admission (a request's, and a preempted request's again on
+        readmission) comes from its prefill's logits, not a decode step."""
+        n = sum(len(t) for t in self.tokens) - len(self.tokens) \
+            - self.preemptions
         return n / max(self.decode_seconds, 1e-9)
 
     def latency_percentile(self, q: float) -> float:
@@ -642,7 +653,7 @@ class ServeEngine:
         for p, m in zip(prompts, max_new):
             if len(p) + m > self.cfg.max_seq:
                 raise ValueError("prompt + generation exceeds max_seq")
-        with self._sharding_ctx():
+        with span(GENERATE, requests=n), self._sharding_ctx():
             if self._continuous:
                 return self._generate_continuous(prompts, max_new,
                                                  frontend_embeds)
@@ -680,6 +691,7 @@ class ServeEngine:
                 if fe.shape[0] < slots:
                     reps = np.repeat(fe[:1], slots - fe.shape[0], axis=0)
                     fe = np.concatenate([fe, reps], axis=0)
+            admit_s = time.time() - t0
             toks, pf, dc, st, nc = self._generate_wave(
                 np.asarray(wave, np.int32), max(wave_new), fe)
             wave_done = time.time() - t0
@@ -689,6 +701,7 @@ class ServeEngine:
                 per_request.append({
                     "rid": wave_start + i, "prompt_len": plen,
                     "new_tokens": len(t),
+                    "admit_s": admit_s,
                     "latency_s": wave_done,
                     "ttft_s": wave_done - dc,
                     "preemptions": 0,  # waves hold slots to completion
@@ -883,6 +896,7 @@ class ServeEngine:
 
     def _generate_continuous(self, prompts, max_new: List[int],
                              frontend_embeds) -> GenerationResult:
+        t0 = time.time()  # every per-request stamp is taken from here
         cfg = self.cfg
         B = cfg.batch_slots
         reqs = []
@@ -938,54 +952,60 @@ class ServeEngine:
         results: List[Optional[List[int]]] = [None] * len(prompts)
         per_request: List[Optional[Dict[str, Any]]] = [None] * len(prompts)
         first_tok_t: Dict[int, float] = {}  # rid -> first-ever-token time
+        admit_t: Dict[int, float] = {}  # rid -> first admission time
         shared_by_rid: Dict[int, int] = {}  # rid -> shared-admitted tokens
         prefill_s = decode_s = 0.0
-        steps = chunks_issued = preemptions = 0
+        steps = chunks_issued = preemptions = admission_blocked = 0
         shared_total = cow_splits = drafted = accepted = 0
-        t0 = time.time()
 
         def run_chunk(b: int) -> None:
             nonlocal cache, prefill_s, chunks_issued
             piece_tokens = slot_chunks[b].pop(0)
-            piece = {"tokens": jnp.asarray(piece_tokens)}
             r = slot_req[b]
-            if slot_first_chunk[b]:
-                slot_first_chunk[b] = False
-                if r.frontend_embeds is not None:
-                    piece["frontend_embeds"] = jnp.asarray(r.frontend_embeds)
-            t = time.time()
-            if self._paged:
-                logits, new_cache = self._slot_chunk_paged(
-                    self.params, piece, cache,
-                    jnp.asarray(page_tables[b]),
-                    jnp.asarray(lengths[b], jnp.int32),
-                    jnp.asarray(b, jnp.int32))
-            else:
-                logits, new_cache = self._slot_chunk(
-                    self.params, piece, cache, jnp.asarray(b, jnp.int32),
-                    jnp.asarray(lengths[b], jnp.int32))
-            cache = new_cache
-            lengths[b] += piece_tokens.shape[1]
-            chunks_issued += 1
-            if not slot_chunks[b]:  # prefill done: sample the next token
-                # publish this prompt's full-chunk groups for sharers;
-                # frontend requests never register — their KV depends on
-                # the embeds, not just the token ids, so content-matched
-                # sharing would alias different activations
+            last = not slot_chunks[b]
+            with span(PREFILL, rid=r.rid, slot=b,
+                      tokens=piece_tokens.shape[1], last=int(last)):
+                piece = {"tokens": jnp.asarray(piece_tokens)}
+                if slot_first_chunk[b]:
+                    slot_first_chunk[b] = False
+                    if r.frontend_embeds is not None:
+                        piece["frontend_embeds"] = jnp.asarray(
+                            r.frontend_embeds)
+                t = time.time()
+                if self._paged:
+                    logits, new_cache = self._slot_chunk_paged(
+                        self.params, piece, cache,
+                        jnp.asarray(page_tables[b]),
+                        jnp.asarray(lengths[b], jnp.int32),
+                        jnp.asarray(b, jnp.int32))
+                else:
+                    logits, new_cache = self._slot_chunk(
+                        self.params, piece, cache, jnp.asarray(b, jnp.int32),
+                        jnp.asarray(lengths[b], jnp.int32))
+                cache = new_cache
+                lengths[b] += piece_tokens.shape[1]
+                chunks_issued += 1
+                if not last:
+                    logits.block_until_ready()
+                    prefill_s += time.time() - t
+                    return
+                # prefill done: publish this prompt's full-chunk groups
+                # for sharers; frontend requests never register — their
+                # KV depends on the embeds, not just the token ids, so
+                # content-matched sharing would alias different
+                # activations
                 if prefix is not None and r.frontend_embeds is None:
                     prefix.register(list(r.prompt),
                                     [int(g) for g in page_tables[b]])
-                # token index = tokens already carried from before a
-                # preemption (0 for fresh requests) — the (rid, index)
-                # sampling key continues exactly where it left off
+                # sample the next token; token index = tokens already
+                # carried from before a preemption (0 for fresh requests)
+                # — the (rid, index) sampling key continues exactly where
+                # it left off
                 tok = int(np.asarray(self._sample_slot(
                     logits, r.rid, len(slot_out[b]))))
                 prefill_s += time.time() - t
                 first_tok_t.setdefault(r.rid, time.time())
-                accept_token(b, tok)
-            else:
-                logits.block_until_ready()
-                prefill_s += time.time() - t
+            accept_token(b, tok)
 
         def accept_token(b: int, tok: int) -> None:
             r = slot_req[b]
@@ -1012,6 +1032,7 @@ class ServeEngine:
             per_request[r.rid] = {
                 "rid": r.rid, "prompt_len": r.prompt_len,
                 "new_tokens": len(slot_out[b]),
+                "admit_s": admit_t[r.rid] - t0,
                 "latency_s": now - t0,
                 "ttft_s": first_tok_t.get(r.rid, now) - t0,
                 "preemptions": r.preemptions,
@@ -1216,10 +1237,45 @@ class ServeEngine:
                 cfg.share_prefix = new_share
             return applied
 
+        def admit(b: int, head: Request, groups, covered: int) -> None:
+            """Seat ``head`` in free slot ``b`` on the reservation the
+            pool granted: page table, shared-prefix credit, prefill
+            chunks and sampling key."""
+            nonlocal shared_total
+            admit_t.setdefault(head.rid, time.time())
+            if window is not None and head.rid not in seen_rids:
+                seen_rids.add(head.rid)  # re-admissions don't
+                window.record_request(steps, head.prompt, head.max_new)
+            if groups is not None:
+                page_tables[b, :] = PageAllocator.SCRATCH_GROUP
+                page_tables[b, :len(groups)] = groups
+            if covered:
+                shared_total += covered
+                shared_by_rid[head.rid] = (
+                    shared_by_rid.get(head.rid, 0) + covered)
+            slot_req[b] = head
+            lengths[b] = covered
+            chunk = cfg.prefill_chunk
+            # a preempted request re-prefills its prompt plus the tokens
+            # it had generated (exact chunked prefill ⇒ identical cache
+            # state to the uninterrupted run); with prefix sharing the
+            # covered leading tokens are already resident in shared
+            # groups, so only the private suffix is prefilled at all —
+            # the TTFT win
+            toks = np.asarray(
+                [(list(head.prompt) + list(head.generated))[covered:]],
+                np.int32)
+            slot_out[b] = list(head.generated)
+            slot_chunks[b] = [toks[:, s:s + chunk]
+                              for s in range(0, toks.shape[1], chunk)]
+            slot_first_chunk[b] = True
+            sample_key_for(b)
+
         def loop() -> None:
-            nonlocal cache, decode_s, steps, shared_total, drafted, accepted
+            nonlocal cache, decode_s, steps, drafted, accepted
+            nonlocal admission_blocked
             while sched.has_pending or any(r is not None for r in slot_req):
-                progressed = False
+                progressed = blocked = False
                 # 1. admission into freed slots, in policy order; only
                 # slots below slot_cap admit (the max_batch knob — slots
                 # at/above a lowered cap simply drain and stay empty)
@@ -1230,38 +1286,11 @@ class ServeEngine:
                         continue
                     admitted = next_admission()
                     if admitted is None:
+                        blocked = True
                         break  # pool full: wait for a release
                     head, groups, covered = admitted
-                    if window is not None and head.rid not in seen_rids:
-                        seen_rids.add(head.rid)  # re-admissions don't
-                        window.record_request(steps, head.prompt,
-                                              head.max_new)
-                    if groups is not None:
-                        page_tables[b, :] = PageAllocator.SCRATCH_GROUP
-                        page_tables[b, :len(groups)] = groups
-                    if covered:
-                        shared_total += covered
-                        shared_by_rid[head.rid] = (
-                            shared_by_rid.get(head.rid, 0) + covered)
-                    slot_req[b] = head
-                    lengths[b] = covered
-                    chunk = cfg.prefill_chunk
-                    # a preempted request re-prefills its prompt plus the
-                    # tokens it had generated (exact chunked prefill ⇒
-                    # identical cache state to the uninterrupted run);
-                    # with prefix sharing the covered leading tokens are
-                    # already resident in shared groups, so only the
-                    # private suffix is prefilled at all — the TTFT win
-                    toks = np.asarray(
-                        [(list(head.prompt)
-                          + list(head.generated))[covered:]],
-                        np.int32)
-                    slot_out[b] = list(head.generated)
-                    slot_chunks[b] = [toks[:, s:s + chunk]
-                                      for s in range(0, toks.shape[1],
-                                                     chunk)]
-                    slot_first_chunk[b] = True
-                    sample_key_for(b)
+                    with span(ADMIT, rid=head.rid, slot=b, covered=covered):
+                        admit(b, head, groups, covered)
                     progressed = True
                     if not sched.interleave_prefill:
                         while slot_chunks[b] and slot_req[b] is not None:
@@ -1315,89 +1344,105 @@ class ServeEngine:
                     active = [b for b in active
                               if slot_req[b] is not None
                               and not slot_chunks[b]]
+                step = steps
                 if active and cfg.draft_len > 0:
                     t = time.time()
                     C = cfg.draft_len + 1
-                    feed = np.zeros((B, C), np.int32)
-                    feed[:, 0] = next_tok
-                    for b, d in drafts.items():
-                        if slot_req[b] is not None:
-                            feed[b, 1:1 + len(d)] = d
-                    logits, new_cache = self._decode_multi(
-                        self.params, jnp.asarray(feed), cache,
-                        jnp.asarray(lengths, jnp.int32),
-                        jnp.asarray(page_tables) if self._paged else None)
-                    if cfg.temperature <= 0:
-                        toks = np.asarray(self._argmax_grid(logits))
-                    else:
-                        produced = jnp.asarray(
-                            [len(slot_out[b]) for b in range(B)], jnp.int32)
-                        toks = np.asarray(self._categorical_grid_j(
-                            logits, base_keys, produced))
+                    with span(DISPATCH, step=step, active=len(active)):
+                        feed = np.zeros((B, C), np.int32)
+                        feed[:, 0] = next_tok
+                        for b, d in drafts.items():
+                            if slot_req[b] is not None:
+                                feed[b, 1:1 + len(d)] = d
+                        logits, new_cache = self._decode_multi(
+                            self.params, jnp.asarray(feed), cache,
+                            jnp.asarray(lengths, jnp.int32),
+                            jnp.asarray(page_tables) if self._paged
+                            else None)
+                        if cfg.temperature <= 0:
+                            picked = self._argmax_grid(logits)
+                        else:
+                            produced = jnp.asarray(
+                                [len(slot_out[b]) for b in range(B)],
+                                jnp.int32)
+                            picked = self._categorical_grid_j(
+                                logits, base_keys, produced)
+                    with span(READBACK, step=step):
+                        toks = np.asarray(picked)
                     cache = new_cache
                     decode_s += time.time() - t
                     steps += 1
+                    admission_blocked += blocked
                     progressed = True
-                    for b in active:
-                        d = drafts.get(b, [])
-                        drafted += len(d)
-                        acc_b = 0
-                        # column 0 is the ordinary sampled token (always
-                        # accepted); column i+1's logits are valid only
-                        # if fed draft token d[i] matched the token
-                        # sampled at column i
-                        for i in range(C):
-                            lengths[b] += 1  # the fed token is resident
-                            first_tok_t.setdefault(slot_req[b].rid,
-                                                   time.time())
-                            tok = int(toks[b, i])
-                            accept_token(b, tok)
-                            if i > 0:
-                                accepted += 1
-                                acc_b += 1
-                            if slot_req[b] is None:
-                                break  # finished mid-chain
-                            if i >= len(d) or tok != d[i]:
-                                break
-                        if window is not None and d:
-                            window.record_draft(len(d), acc_b)
+                    with span(ACCEPT, step=step):
+                        for b in active:
+                            d = drafts.get(b, [])
+                            drafted += len(d)
+                            acc_b = 0
+                            # column 0 is the ordinary sampled token
+                            # (always accepted); column i+1's logits are
+                            # valid only if fed draft token d[i] matched
+                            # the token sampled at column i
+                            for i in range(C):
+                                lengths[b] += 1  # the fed token is resident
+                                first_tok_t.setdefault(slot_req[b].rid,
+                                                       time.time())
+                                tok = int(toks[b, i])
+                                accept_token(b, tok)
+                                if i > 0:
+                                    accepted += 1
+                                    acc_b += 1
+                                if slot_req[b] is None:
+                                    break  # finished mid-chain
+                                if i >= len(d) or tok != d[i]:
+                                    break
+                            if window is not None and d:
+                                window.record_draft(len(d), acc_b)
                 elif active:
                     t = time.time()
-                    logits, new_cache = self._decode_multi(
-                        self.params, jnp.asarray(next_tok[:, None]), cache,
-                        jnp.asarray(lengths, jnp.int32),
-                        jnp.asarray(page_tables) if self._paged else None)
-                    if cfg.temperature <= 0:
-                        toks = np.asarray(self._argmax_multi(logits))
-                    else:
-                        produced = jnp.asarray(
-                            [len(slot_out[b]) for b in range(B)], jnp.int32)
-                        toks = np.asarray(self._categorical_multi(
-                            logits, base_keys, produced))
+                    with span(DISPATCH, step=step, active=len(active)):
+                        logits, new_cache = self._decode_multi(
+                            self.params, jnp.asarray(next_tok[:, None]),
+                            cache, jnp.asarray(lengths, jnp.int32),
+                            jnp.asarray(page_tables) if self._paged
+                            else None)
+                        if cfg.temperature <= 0:
+                            picked = self._argmax_multi(logits)
+                        else:
+                            produced = jnp.asarray(
+                                [len(slot_out[b]) for b in range(B)],
+                                jnp.int32)
+                            picked = self._categorical_multi(
+                                logits, base_keys, produced)
+                    with span(READBACK, step=step):
+                        toks = np.asarray(picked)
                     cache = new_cache
                     decode_s += time.time() - t
                     steps += 1
+                    admission_blocked += blocked
                     progressed = True
-                    for b in active:
-                        lengths[b] += 1  # the fed token is now resident
-                        first_tok_t.setdefault(slot_req[b].rid, time.time())
-                        tok = int(toks[b])
-                        if window is not None:
-                            # shadow probe: what WOULD 1-token n-gram
-                            # drafting have proposed, and would it have
-                            # been accepted?  Feeds a measured
-                            # acceptance rate even while draft_len=0,
-                            # so the retuner can justify switching
-                            # speculation ON — without it the loop
-                            # could only ever turn it off.
-                            pred = self._ngram_draft(
-                                _tail_history(slot_req[b].prompt,
-                                              slot_out[b],
-                                              cfg.draft_window), 1)
-                            if pred:
-                                window.record_draft(
-                                    1, 1 if pred[0] == tok else 0)
-                        accept_token(b, tok)
+                    with span(ACCEPT, step=step):
+                        for b in active:
+                            lengths[b] += 1  # the fed token is now resident
+                            first_tok_t.setdefault(slot_req[b].rid,
+                                                   time.time())
+                            tok = int(toks[b])
+                            if window is not None:
+                                # shadow probe: what WOULD 1-token n-gram
+                                # drafting have proposed, and would it
+                                # have been accepted?  Feeds a measured
+                                # acceptance rate even while draft_len=0,
+                                # so the retuner can justify switching
+                                # speculation ON — without it the loop
+                                # could only ever turn it off.
+                                pred = self._ngram_draft(
+                                    _tail_history(slot_req[b].prompt,
+                                                  slot_out[b],
+                                                  cfg.draft_window), 1)
+                                if pred:
+                                    window.record_draft(
+                                        1, 1 if pred[0] == tok else 0)
+                            accept_token(b, tok)
                 if window is not None:
                     window.record_depth(
                         sched.queue_depth
@@ -1430,7 +1475,7 @@ class ServeEngine:
             chunks_issued, [dict(r) for r in per_request],
             preemptions=preemptions, shared_prefix_tokens=shared_total,
             cow_splits=cow_splits, drafted=drafted, accepted=accepted,
-            retunes=retunes)
+            retunes=retunes, admission_blocked=admission_blocked)
 
     def _sample_slot(self, logits, rid: int, produced: int):
         """Sample ONE request's next token from (1, S, V) logits, keyed by

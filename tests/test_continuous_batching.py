@@ -193,6 +193,30 @@ class TestPagePolicy:
         assert sum(r["preemptions"] for r in on_demand.per_request) \
             == on_demand.preemptions
 
+    def test_decode_tokens_exclude_prefill_tokens(self, engine,
+                                                  monkeypatch):
+        """Every admission's first token (a readmission's too) comes from
+        its prefill's logits; each decode step gives one token per active
+        slot, which the ``serve.dispatch`` span's ``active`` counts."""
+        import contextlib
+
+        import repro.serve.engine as engine_mod
+
+        active = []
+
+        def span(name, **ids):
+            if name == "serve.dispatch":
+                active.append(ids["active"])
+            return contextlib.nullcontext()
+
+        monkeypatch.setattr(engine_mod, "span", span)
+        res = self._run(engine, "on_demand")
+        assert res.preemptions > 0 and len(active) == res.steps
+        tokens = sum(len(t) for t in res.tokens)
+        assert sum(active) == tokens - len(res.tokens) - res.preemptions
+        assert res.decode_tokens_per_sec == pytest.approx(
+            sum(active) / res.decode_seconds)
+
     def test_policy_parity_across_schedules(self, engine):
         outs = [self._run(engine, "on_demand", schedule=s).tokens
                 for s in ("fifo", "sjf", "interleave")]
@@ -286,6 +310,43 @@ class TestPerRequestStats:
         assert res.p50_latency_s <= res.p95_latency_s
         assert res.decode_tokens_per_sec > 0
 
+    def test_admit_stamp_precedes_first_token(self, engine):
+        """``admit_s`` is the first admission to a slot, from the call's
+        start: fifo over two slots seats rids 0 and 1 at once, and rid 2
+        only after one of them has finished."""
+        model, params = engine
+        for layout in ("dense", "paged"):
+            res = ServeEngine(model, params, _cfg(kv_layout=layout)
+                              ).generate(PROMPTS, MAX_NEW)
+            for r in res.per_request:
+                assert 0 <= r["admit_s"] <= r["ttft_s"] <= r["latency_s"]
+            admits = [r["admit_s"] for r in res.per_request]
+            assert admits == sorted(admits)
+            assert admits[2] >= min(r["latency_s"]
+                                    for r in res.per_request[:2])
+
+    def test_decode_tokens_per_sec_counts_decode_steps_only(self, engine):
+        model, params = engine
+        res = ServeEngine(model, params, _cfg(kv_layout="paged")).generate(
+            PROMPTS, MAX_NEW)
+        assert [len(t) for t in res.tokens] == MAX_NEW
+        # 27 tokens; each request's first comes from its prefill
+        assert res.decode_tokens_per_sec == pytest.approx(
+            (sum(MAX_NEW) - len(PROMPTS)) / res.decode_seconds)
+
+    def test_admission_blocked_counts_pool_refusals(self, engine):
+        """A pool that holds about one reservation refuses admission while
+        slots are free, before some decode steps; an ample pool and dense
+        buffers never refuse."""
+        model, params = engine
+        small, big, dense = (ServeEngine(model, params, _cfg(
+            kv_layout=layout, batch_slots=3, **kw)).generate(
+                PROMPTS, MAX_NEW) for layout, kw in (
+                    ("paged", {"kv_cache_pages": 3}), ("paged", {}),
+                    ("dense", {})))
+        assert 0 < small.admission_blocked <= small.steps
+        assert big.admission_blocked == dense.admission_blocked == 0
+
     def test_wave_runtime_also_reports(self, engine):
         model, params = engine
         eng = ServeEngine(model, params, ServeConfig(
@@ -293,6 +354,7 @@ class TestPerRequestStats:
         res = eng.generate([[1, 2, 3]] * 5, 3)
         assert len(res.per_request) == 5
         assert all(r["latency_s"] > 0 for r in res.per_request)
+        assert all(0 <= r["admit_s"] <= r["ttft_s"] for r in res.per_request)
         # wave w+1 finishes after wave w
         lats = [r["latency_s"] for r in res.per_request]
         assert lats == sorted(lats)
