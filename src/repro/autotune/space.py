@@ -343,14 +343,15 @@ def _rn_cost(config, d, dtype):
 # -- paged decode attention --------------------------------------------------
 # the authoritative page granularity (serve/paging.py is numpy-only, so
 # this import stays cheap and the two can never drift)
-from repro.serve.paging import PAGE_TOKENS  # noqa: E402
+from repro.serve.paging import PAGE_TOKENS, block_groups  # noqa: E402
 
 
 def _pa_space() -> ParameterSpace:
-    # pages_per_block is the pool-layout granularity: tokens streamed per
-    # grid step = pages_per_block * PAGE_TOKENS.  The serve engine's paged
-    # allocator adopts the tuned value as its group size, so the knob
-    # couples kernel tiling with allocator fragmentation.
+    # pages_per_block is the pool-layout granularity: a page group holds
+    # pages_per_block * PAGE_TOKENS tokens, and the kernel streams as many
+    # whole groups a grid step as fill its block.  The serve engine's
+    # paged allocator adopts the tuned value as its group size, so the
+    # knob couples kernel tiling with allocator fragmentation.
     return _with_launch_knobs([
         EnumParam("pages_per_block", (1, 2, 4, 8, 16, 32), 4),
     ])
@@ -389,27 +390,40 @@ def _pa_call(inputs, config, interpret):
         num_warps=config.get("num_warps"), interpret=interpret)
 
 
-def _pa_vmem(config, d, dtype):
-    """Resident tiles: k/v page blocks + per-group f32 m/l/acc + queries."""
-    G = max(d["H"] // d["KV"], 1)
+def _pa_tiling(config, d, dtype):
+    """(T, MAXG, n): group tokens, groups per slot, groups per grid step
+    — the kernel's own rule for ``n``, so model and kernel agree."""
     T = min(int(config["pages_per_block"]) * PAGE_TOKENS, d["S"])
+    maxg = math.ceil(d["S"] / T)
+    return T, maxg, block_groups(d["KV"], T, d["D"], _dtype_bytes(dtype),
+                                 maxg)
+
+
+def _pa_vmem(config, d, dtype):
+    """Resident tiles: the double-buffered K and V blocks of ``n`` whole
+    groups (all KV heads), f32 m/l/acc for every query head, and the
+    pipelined query and output blocks of one slot."""
+    T, _, n = _pa_tiling(config, d, dtype)
     ib = _dtype_bytes(dtype)
-    return 2 * T * d["D"] * ib + G * (2 + d["D"]) * 4 + G * d["D"] * ib
+    KV, D = d["KV"], d["D"]
+    G = max(d["H"] // KV, 1)
+    return (2 * 2 * n * KV * T * D * ib + KV * G * (2 + D) * 4
+            + 2 * 2 * KV * G * D * ib)
 
 
 def _pa_cost(config, d, dtype):
-    B, S, H, KV, D = d["B"], d["S"], d["H"], d["KV"], d["D"]
+    B, H, KV, D = d["B"], d["H"], d["KV"], d["D"]
     G = max(H // KV, 1)
-    T = min(int(config["pages_per_block"]) * PAGE_TOKENS, S)
-    ng = math.ceil(S / T)
-    n_steps = B * KV * ng
+    T, maxg, n = _pa_tiling(config, d, dtype)
+    n_steps = B * math.ceil(maxg / n)
     ib = _dtype_bytes(dtype)
-    flops = n_steps * 4.0 * G * T * D * _align_penalty(T, dtype)
-    # stream the pool once + the page-table walk (one SMEM-indexed DMA
-    # program per group — small but real, and it shrinks as T grows)
-    hbm = 2.0 * B * KV * ng * T * D * ib + n_steps * 64.0
+    # every step computes a whole block of n groups, all heads
+    flops = n_steps * KV * 4.0 * G * n * T * D * _align_penalty(T, dtype)
+    # the inputs hold S valid tokens a slot: whole groups of all heads,
+    # one DMA descriptor (K and V) per group
+    hbm = 2.0 * B * maxg * KV * T * D * ib + 2 * B * maxg * 64.0
     vmem = _pa_vmem(config, d, dtype)
-    return _roofline_s(flops, hbm, n_steps, vmem, config, T * D)
+    return _roofline_s(flops, hbm, n_steps, vmem, config, n * KV * T * D)
 
 
 KERNELS: Dict[str, KernelDef] = {

@@ -5,26 +5,28 @@ page *groups* (``repro.serve.paging``); a request's tokens live in
 whatever groups the allocator handed it, in logical order given by its
 page-table row.  Dense decode attention would first gather the pool into
 a contiguous per-slot buffer — an extra O(B·S) HBM round trip per step.
-This kernel streams the pool *directly*: the page table rides in as a
-scalar-prefetch operand, so each grid step's K/V block is DMA'd straight
-from its physical group (``index_map`` reads the page table — the Pallas
-TPU idiom for data-dependent addressing).
+This kernel streams the pool *directly*: the page table and the lengths
+ride in as scalar-prefetch operands, and the kernel copies each valid
+group from its physical place in HBM into VMEM itself.
 
-Layout: the pool is head-major, (groups, KV-head, T, D), so one page
-group of one KV head is a contiguous (T, D) slab — the kernel's block.
-Its last two dims are T (a multiple of ``PAGE_TOKENS``) and the full
-head_dim, which is what Mosaic's (8, 128) block rule accepts at every
-head_dim.  Grid (B, KV-head, logical-groups); all G query heads of a KV
-group are processed together as a (G, D) tile (decode_attention's GQA
-bandwidth win, unchanged).  Online-softmax state lives in 2-D VMEM
-scratch across the group dimension; groups past a sequence's valid
-length are skipped with ``pl.when`` — a 2k-token request in a
-32k-capacity pool streams 2k tokens, and *only its own* pages.
+Layout: the pool is (groups, KV-head, T, D), so one page group with all
+its KV heads is one contiguous (KV, T, D) slab.  Grid (B, blocks): a
+grid step takes ``n`` whole groups of one slot (``block_groups``: about
+1 MiB of K, 8 groups at Qwen2.5-32B widths), one DMA of K and one of V
+per group, into a VMEM double buffer; it starts the next step's copies
+before it computes on its own.  Groups at or past a slot's length get no
+copy, and blocks past it no work, so a 2k-token request in a
+32k-capacity pool streams 2k tokens, and *only its own* pages.  The
+query and output blocks are one slot's (KV, Gq, D).  Scores and P·V of
+a step's n groups and all heads are two batched f32 matmuls; the f32
+online softmax (state in (KV, Gq, ·) VMEM scratch) then advances one
+group at a time, so the output is bit-identical to a grid of one group
+a step.
 
 ``pages_per_block`` is structural here: the pool's token axis is
 ``pages_per_block * PAGE_TOKENS`` tokens, so the tuning knob is applied
-where the pool is laid out (engine/allocator) and this kernel simply
-tiles one group per grid step.
+where the pool is laid out (engine/allocator); the kernel picks ``n``
+from the shape it sees.
 """
 from __future__ import annotations
 
@@ -37,21 +39,23 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.serve.paging import block_groups
+
 __all__ = ["POOL_AXES", "gather_pages", "paged_flash_decode_pallas",
            "paged_attention_ref", "shardable_kv_heads"]
 
 NEG_INF = -1e30
 
 # Logical sharding axes of the (G, KV, T, D) KV pool this kernel streams.
-# The group and token axes are deliberately unsharded: the grid tiles ONE
-# physical group per step through the scalar-prefetched page table, so a
+# The group and token axes are deliberately unsharded: the kernel copies
+# whole physical groups through the scalar-prefetched page table, so a
 # split along groups would scatter a request's logically-contiguous pages
-# across devices and break the ``index_map`` addressing.  Only the KV-head
-# axis splits (tensor parallelism): each model-axis shard streams its own
-# heads over the full pool, and the kernel's (pages_per_block x
-# PAGE_TOKENS) group tile stays aligned with the allocator's group size on
-# every shard.  ``repro.models.transformer.paged_cache_block_defs`` builds
-# pool ParamDefs from this tuple — one source for the kernel/allocator/
+# across devices and break that addressing.  Only the KV-head axis splits
+# (tensor parallelism): each model-axis shard streams its own heads over
+# the full pool, and the kernel's (pages_per_block x PAGE_TOKENS) group
+# tile stays aligned with the allocator's group size on every shard.
+# ``repro.models.transformer.paged_cache_block_defs`` builds pool
+# ParamDefs from this tuple — one source for the kernel/allocator/
 # sharding coupling.
 POOL_AXES = (None, "kv_heads", None, "head_dim")
 
@@ -70,43 +74,89 @@ def shardable_kv_heads(n_kv_heads: int, model_size: int) -> bool:
     return m == 1 or int(n_kv_heads) % m == 0
 
 
-def _kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-            acc_ref, *, group_tokens: int, scale: float):
+def _kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf,
+            sems, m_ref, l_ref, acc_ref, *, scale: float):
     b = pl.program_id(0)
-    g = pl.program_id(2)
-    ng = pl.num_programs(2)
+    j = pl.program_id(1)
+    nj = pl.num_programs(1)
+    _, n, KV, T, D = k_buf.shape
+    maxg = pt_ref.shape[1]
     length = len_ref[b]
+    n_groups = jnp.minimum((length + T - 1) // T, maxg)
+    n_blocks = (n_groups + n - 1) // n
+    slot = j % 2
 
-    @pl.when(g == 0)
+    def each_group(blk, buf_slot, fn):
+        # one (KV, T, D) slab of K and of V per valid group of block blk;
+        # no copy for groups at or past the slot's length
+        for i in range(n):
+            g = blk * n + i
+
+            @pl.when(g < n_groups)
+            def _():
+                page = pt_ref[b, g]
+                fn(pltpu.make_async_copy(k_hbm.at[page],
+                                         k_buf.at[buf_slot, i],
+                                         sems.at[0, buf_slot]))
+                fn(pltpu.make_async_copy(v_hbm.at[page],
+                                         v_buf.at[buf_slot, i],
+                                         sems.at[1, buf_slot]))
+
+    @pl.when(j == 0)
     def init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    base = g * group_tokens
+        @pl.when(n_blocks > 0)
+        def first_fetch():
+            each_group(0, 0, lambda c: c.start())
 
-    @pl.when(base < length)  # skip groups past the valid length
+    @pl.when(j < n_blocks)  # blocks past the valid length do nothing
     def compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale  # (G, D)
-        k = k_ref[0, 0].astype(jnp.float32)  # (T, D)
-        v = v_ref[0, 0].astype(jnp.float32)  # (T, D)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        kpos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kpos < length, s, NEG_INF)
-        m_prev = m_ref[...]  # (G, 1)
-        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        @pl.when(j + 1 < n_blocks)
+        def prefetch():
+            each_group(j + 1, 1 - slot, lambda c: c.start())
 
-    @pl.when(g == ng - 1)
+        each_group(j, slot, lambda c: c.wait())
+        # Scores and P·V for all n groups and KV heads come from two
+        # batched matmuls; the online softmax then steps group by group,
+        # as a grid of one group a step would, and gives its bits.
+        Gq = q_ref.shape[2]
+        q = q_ref[0].astype(jnp.float32) * scale  # (KV, Gq, D)
+        qb = jnp.broadcast_to(q[None], (n, KV, Gq, D)).reshape(
+            n * KV, Gq, D)
+        grp = j * n + jax.lax.broadcasted_iota(jnp.int32, (n, 1, 1, 1), 0)
+        k = k_buf[slot].astype(jnp.float32).reshape(n * KV, T, D)
+        # groups past the length hold stale VMEM: zero V there so that
+        # 0 * garbage cannot reach the accumulator
+        v = jnp.where(grp < n_groups, v_buf[slot].astype(jnp.float32), 0.0)
+        s = jax.lax.dot_general(qb, k, (((2,), (2,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32)
+        s = s.reshape(n, KV, Gq, T)
+        kpos = grp * T + jax.lax.broadcasted_iota(jnp.int32, (n, 1, 1, T), 3)
+        s = jnp.where(kpos < length, s, NEG_INF)
+        ms = [m_ref[...]]  # running max after each group, (KV, Gq, 1)
+        for i in range(n):
+            ms.append(jnp.maximum(ms[-1], s[i].max(axis=2, keepdims=True)))
+        p = jnp.exp(s - jnp.stack(ms[1:]))
+        pv = jax.lax.dot_general(p.reshape(n * KV, Gq, T),
+                                 v.reshape(n * KV, T, D),
+                                 (((2,), (1,)), ((0,), (0,))),
+                                 preferred_element_type=jnp.float32)
+        pv = pv.reshape(n, KV, Gq, D)
+        # a group past the length is a no-op here: alpha 1, p and P·V 0
+        l, acc = l_ref[...], acc_ref[...]
+        for i in range(n):
+            alpha = jnp.exp(ms[i] - ms[i + 1])
+            l = l * alpha + p[i].sum(axis=2, keepdims=True)
+            acc = acc * alpha + pv[i]
+        m_ref[...], l_ref[...], acc_ref[...] = ms[n], l, acc
+
+    @pl.when(j == nj - 1)
     def finalize():
         denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 def paged_flash_decode_pallas(
@@ -126,40 +176,42 @@ def paged_flash_decode_pallas(
     if H % KV:
         raise ValueError(f"H={H} not a multiple of KV={KV}")
     Gq = H // KV
+    n = block_groups(KV, T, D, k_pages.dtype.itemsize, MAXG)
     qg = q.reshape(B, KV, Gq, D)
     page_table = jnp.asarray(page_table, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
 
-    # The batch and head grid dims are embarrassingly parallel; the group
-    # dim carries the online-softmax scratch and must stay "arbitrary".
+    # Each slot's copies start and end inside its own grid steps, so the
+    # batch dim is embarrassingly parallel; the block dim carries the
+    # online-softmax scratch and the double buffer and stays "arbitrary".
     # num_warps is accepted for signature parity with the GPU lowering
     # (where it would reach the Triton compiler); Mosaic has no analog.
     from .launch import launch_params
 
-    params = launch_params(dimension_semantics, 3, 1, interpret)
+    params = launch_params(dimension_semantics, 2, 1, interpret)
     del num_warps
 
     kwargs = {"compiler_params": params} if params else {}
     out = pl.pallas_call(
-        functools.partial(_kernel, group_tokens=T,
-                          scale=1.0 / math.sqrt(D)),
+        functools.partial(_kernel, scale=1.0 / math.sqrt(D)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,  # page_table, lengths
-            grid=(B, KV, MAXG),
+            grid=(B, pl.cdiv(MAXG, n)),
             in_specs=[
-                pl.BlockSpec((1, 1, Gq, D),
-                             lambda b, h, g, pt, ln: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, T, D),
-                             lambda b, h, g, pt, ln: (pt[b, g], h, 0, 0)),
-                pl.BlockSpec((1, 1, T, D),
-                             lambda b, h, g, pt, ln: (pt[b, g], h, 0, 0)),
+                pl.BlockSpec((1, KV, Gq, D),
+                             lambda b, j, pt, ln: (b, 0, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec((1, 1, Gq, D),
-                                   lambda b, h, g, pt, ln: (b, h, 0, 0)),
+            out_specs=pl.BlockSpec((1, KV, Gq, D),
+                                   lambda b, j, pt, ln: (b, 0, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((Gq, 1), jnp.float32),
-                pltpu.VMEM((Gq, 1), jnp.float32),
-                pltpu.VMEM((Gq, D), jnp.float32),
+                pltpu.VMEM((2, n, KV, T, D), k_pages.dtype),
+                pltpu.VMEM((2, n, KV, T, D), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),  # (K|V, buffer)
+                pltpu.VMEM((KV, Gq, 1), jnp.float32),
+                pltpu.VMEM((KV, Gq, 1), jnp.float32),
+                pltpu.VMEM((KV, Gq, D), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, KV, Gq, D), q.dtype),

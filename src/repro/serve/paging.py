@@ -2,12 +2,12 @@
 
 The serving engine's KV memory is a pool of ``PAGE_TOKENS``-token pages.
 Requests own *groups* of ``pages_per_group`` physically-contiguous pages
-(the paged decode-attention kernel fetches one group per grid step, so the
-group size is simultaneously the allocator granularity and the kernel's
-``pages_per_block`` tiling knob — the scheduler×pager×kernel coupling the
-co-tuner exercises).  Group 0 is a reserved scratch group: idle engine
-slots park their page tables on it, so masked-out decode lanes can never
-write into live requests' memory.
+(the paged decode-attention kernel copies whole groups, several per grid
+step, so the group size is simultaneously the allocator granularity and
+the kernel's ``pages_per_block`` tiling knob — the
+scheduler×pager×kernel coupling the co-tuner exercises).  Group 0 is a
+reserved scratch group: idle engine slots park their page tables on it,
+so masked-out decode lanes can never write into live requests' memory.
 
 Groups are **refcounted**: ``share`` maps an additional owner onto groups
 another request already holds (prefix sharing — several page tables point
@@ -28,9 +28,24 @@ from __future__ import annotations
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 __all__ = ["PAGE_TOKENS", "OversubscriptionError", "PageAllocator",
-           "PrefixIndex", "min_pages_for"]
+           "PrefixIndex", "block_groups", "min_pages_for"]
 
 PAGE_TOKENS = 16  # KV-cache page granularity (tokens per page)
+# K bytes one grid step of the paged decode kernel streams, about; V
+# streams as much again.
+BLOCK_BYTES = 1 << 20
+
+
+def block_groups(n_kv_heads: int, group_tokens: int, head_dim: int,
+                 itemsize: int, max_groups: int) -> int:
+    """Page groups the paged decode kernel streams a grid step: enough
+    for about ``BLOCK_BYTES`` of K, at least one and at most a slot's
+    whole page-table row.  At Qwen2.5-32B widths (KV 8, T 64, D 128,
+    bf16) that is 8 groups, 512 tokens; a TP shard's KV 2 gives 32.
+    It lives here, free of jax, so that the kernel and its autotune cost
+    model share one rule."""
+    slab = n_kv_heads * group_tokens * head_dim * itemsize
+    return max(1, min(BLOCK_BYTES // slab, max_groups))
 
 
 def min_pages_for(max_tokens: int, pages_per_group: int = 1) -> int:

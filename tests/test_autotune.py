@@ -58,6 +58,8 @@ class TestCostModel:
         ("decode_attention", FA_DIMS),
         ("gla", {"B": 1, "S": 256, "H": 2, "DK": 32, "DV": 32}),
         ("rmsnorm", {"ROWS": 1024, "D": 512}),
+        ("paged_attention", {"B": 32, "S": 4096, "H": 40, "KV": 8,
+                             "D": 128}),
     ])
     def test_model_finite_and_positive(self, kernel, dims):
         d = KERNELS[kernel]
@@ -67,6 +69,24 @@ class TestCostModel:
                     space.from_unit_vector(np.full(space.dim, 0.97))]:
             cost = d.model_cost(cfg, dims, "float32")
             assert cost > 0
+
+    @pytest.mark.parametrize("ppb,n", [(1, 32), (4, 8), (32, 1)])
+    def test_paged_model_follows_the_kernel_tiling(self, ppb, n):
+        """Qwen2.5-32B decode on one chip: a grid step streams n whole
+        page groups of all 8 KV heads, double-buffered, so the footprint
+        is 2 x (K, V) x n x KV x T x D plus the f32 softmax state and the
+        query and output blocks; steps are B x ceil(MAXG / n)."""
+        from repro.autotune.space import _pa_tiling
+
+        d = KERNELS["paged_attention"]
+        dims = {"B": 32, "S": 4096, "H": 40, "KV": 8, "D": 128}
+        cfg = {"pages_per_block": ppb}
+        T, maxg, got = _pa_tiling(cfg, dims, "bfloat16")
+        assert (T, maxg, got) == (16 * ppb, 4096 // (16 * ppb), n)
+        blocks = 2 * 2 * n * 8 * T * 128 * 2
+        vmem = d.vmem_footprint(cfg, dims, "bfloat16")
+        assert blocks < vmem < blocks + 2**20
+        assert d.model_cost(cfg, dims, "bfloat16") < float("inf")
 
     def test_vmem_overflow_is_infeasible(self):
         d = KERNELS["flash_attention"]

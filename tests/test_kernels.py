@@ -216,32 +216,64 @@ class TestPagedDecode:
         pt = jnp.asarray(perm.reshape(B, maxg), jnp.int32)
         return kp, vp, pt
 
+    @staticmethod
+    def _blocks_of(monkeypatch, n, KV, T, D, dtype):
+        """Shrink the kernel's block to ``n`` groups, so that tiny shapes
+        still take several grid steps a slot."""
+        from repro.serve import paging
+
+        monkeypatch.setattr(paging, "BLOCK_BYTES",
+                            n * KV * T * D * jnp.dtype(dtype).itemsize)
+
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     @pytest.mark.parametrize("B,H,KV,D,T,maxg", [
         (1, 2, 2, 8, 16, 2),
         (2, 8, 2, 16, 32, 3),   # GQA, multi-page
         (3, 4, 1, 32, 16, 4),   # MQA
+        # six slots at the edge lengths 0, 1, T, nT, nT+1, MAXG*T, with
+        # KV in {1, 2, 8} and Gq in {1, 5}; MAXG is no multiple of n = 3
+        (6, 1, 1, 8, 64, 5),
+        (6, 5, 1, 8, 16, 7),
+        (6, 2, 2, 16, 16, 8),
+        (6, 10, 2, 16, 64, 5),
+        (6, 8, 8, 8, 16, 7),
+        (6, 40, 8, 8, 64, 4),
     ])
-    def test_against_refs(self, dtype, B, H, KV, D, T, maxg):
+    def test_against_refs(self, monkeypatch, dtype, B, H, KV, D, T, maxg):
         from repro.kernels.paged_attention import (gather_pages,
                                                    paged_attention_ref,
                                                    paged_flash_decode_pallas)
 
+        n = 3  # groups a grid step
+        self._blocks_of(monkeypatch, n, KV, T, D, dtype)
         rng = np.random.default_rng(hash((B, H, KV, D, T)) % 2**31)
         q = _rand(rng, (B, H, D), dtype)
         kp, vp, pt = self._pool(rng, B, maxg, T, KV, D, dtype)
-        lengths = jnp.asarray(
-            rng.integers(1, maxg * T, size=B), jnp.int32)
+        if B == 6:
+            lengths = np.minimum([0, 1, T, n * T, n * T + 1, maxg * T],
+                                 maxg * T)
+        else:
+            lengths = rng.integers(1, maxg * T, size=B)
+        # as in the engine, groups past a slot's length map to scratch 0
+        pt = np.array(pt)
+        for b in range(B):
+            pt[b, -(-int(lengths[b]) // T):] = 0
+        pt = jnp.asarray(pt)
+        lengths = jnp.asarray(lengths, jnp.int32)
         out = paged_flash_decode_pallas(q, kp, vp, pt, lengths,
                                         interpret=True)
         ref = paged_attention_ref(q, kp, vp, pt, lengths)
+        live = np.asarray(lengths) > 0
         np.testing.assert_allclose(
-            np.asarray(out, np.float32), np.asarray(ref, np.float32),
-            **TOL[dtype])
+            np.asarray(out, np.float32)[live],
+            np.asarray(ref, np.float32)[live], **TOL[dtype])
+        # an idle slot (length 0) reads zeros, not NaN
+        np.testing.assert_array_equal(
+            np.asarray(out, np.float32)[~live], 0.0)
         # gather-to-dense oracle per sequence
         kd = gather_pages(kp, pt)
         vd = gather_pages(vp, pt)
-        for b in range(B):
+        for b in np.flatnonzero(live):
             L = int(lengths[b])
             dense = attention_ref(q[b:b + 1, None], kd[b:b + 1, :L],
                                   vd[b:b + 1, :L], causal=False)[:, 0]
@@ -249,13 +281,44 @@ class TestPagedDecode:
                 np.asarray(out[b:b + 1], np.float32),
                 np.asarray(dense, np.float32), **TOL[dtype])
 
-    def test_page_table_permutation_invariance(self):
+    @pytest.mark.parametrize("KV,T,D,itemsize,maxg,n", [
+        (8, 64, 128, 2, 64, 8),    # Qwen2.5-32B on one chip: 512 tokens
+        (2, 64, 128, 2, 64, 32),   # its TP (1, 4) shard
+        (8, 512, 128, 2, 8, 1),    # pages_per_block 32: one group a step
+        (2, 16, 8, 4, 5, 5),       # tiny: the whole row in one step
+    ])
+    def test_block_groups(self, KV, T, D, itemsize, maxg, n):
+        from repro.serve.paging import block_groups
+
+        assert block_groups(KV, T, D, itemsize, maxg) == n
+
+    @pytest.mark.parametrize("KV,Gq,T", [(2, 5, 16), (8, 1, 64)])
+    def test_groups_per_step_leave_the_bits(self, monkeypatch, KV, Gq, T):
+        """The softmax advances one group at a time whatever the block,
+        so 1, 2 or 3 groups a grid step give the same bits."""
+        from repro.kernels.paged_attention import paged_flash_decode_pallas
+
+        rng = np.random.default_rng(5)
+        B, D, maxg = 4, 16, 5
+        q = _rand(rng, (B, KV * Gq, D), jnp.float32)
+        kp, vp, pt = self._pool(rng, B, maxg, T, KV, D, jnp.float32)
+        lengths = jnp.asarray([1, 2 * T + 3, 4 * T, maxg * T], jnp.int32)
+        outs = []
+        for n in (1, 2, 3):
+            self._blocks_of(monkeypatch, n, KV, T, D, jnp.float32)
+            outs.append(np.asarray(paged_flash_decode_pallas(
+                q, kp, vp, pt, lengths, interpret=True)))
+        np.testing.assert_array_equal(outs[0], outs[1])
+        np.testing.assert_array_equal(outs[0], outs[2])
+
+    def test_page_table_permutation_invariance(self, monkeypatch):
         """Physically scattering the same logical cache across different
         groups must not change the output at all."""
         from repro.kernels.paged_attention import paged_flash_decode_pallas
 
         rng = np.random.default_rng(11)
         B, H, KV, D, T, maxg = 2, 4, 2, 16, 16, 3
+        self._blocks_of(monkeypatch, 2, KV, T, D, jnp.float32)
         q = _rand(rng, (B, H, D), jnp.float32)
         logical_k = _rand(rng, (B, maxg * T, KV, D), jnp.float32)
         logical_v = _rand(rng, (B, maxg * T, KV, D), jnp.float32)

@@ -110,7 +110,7 @@ def test_checks_resolve_real_sites():
                                     + fl._index_maps(out_specs))
     assert n_jit >= 6        # ops.py wrappers + dryrun prefill_step
     assert n_pallas == 5     # one per kernel module
-    assert n_index_maps >= 20
+    assert n_index_maps >= 19  # the paged kernel copies K/V itself
 
 
 def test_cli_json_and_exit_codes(tmp_path):

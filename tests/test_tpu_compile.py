@@ -53,19 +53,23 @@ def _qwen():
 
 @pytest.mark.parametrize("semantics", SEMANTICS)
 def test_paged_attention(one_chip, semantics):
+    """At the chat cell's shapes (32 slots of 64 page groups of 64 tokens,
+    a 641-group pool), whole and as one TP (1, 4) shard sees it (KV 2):
+    one Mosaic kernel each."""
     from repro.kernels.paged_attention import paged_flash_decode_pallas
 
     H, KV, D, _ = _qwen()
-    B, T, maxg = 8, 64, 17
-    G = B * maxg + 1
+    B, T, maxg, G = 32, 64, 64, 641
     s = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
         shape, dt, sharding=one_chip)
-    text = _compiled_text(
-        lambda q, k, v, pt, ln: paged_flash_decode_pallas(
-            q, k, v, pt, ln, dimension_semantics=semantics),
-        s((B, H, D)), s((G, KV, T, D)), s((G, KV, T, D)),
-        s((B, maxg), jnp.int32), s((B,), jnp.int32))
-    assert "tpu_custom_call" in text
+    for kv in (KV, KV // 4):
+        h = H // KV * kv
+        text = _compiled_text(
+            lambda q, k, v, pt, ln: paged_flash_decode_pallas(
+                q, k, v, pt, ln, dimension_semantics=semantics),
+            s((B, h, D)), s((G, kv, T, D)), s((G, kv, T, D)),
+            s((B, maxg), jnp.int32), s((B,), jnp.int32))
+        assert text.count("tpu_custom_call") == 1, kv
 
 
 @pytest.mark.parametrize("semantics", SEMANTICS)
