@@ -53,7 +53,7 @@ def main(argv=None) -> int:
     rows = []
     for seed in sorted(set(args.seeds) | set(args.control_seeds)):
         t = time.time()
-        prog = Program(cell.config_name, cell.config, seed)
+        prog = Program(cell.config_name, cell.config, seed, cell.bench_dir)
         mix = gen.make(cell.traffic, prog.dims.vocab, seed)
         prog.start()
         prog.generate(*prog.warm_prompts(mix.shapes))
@@ -69,7 +69,8 @@ def main(argv=None) -> int:
                                       "fp8")):
             if seed in want:
                 got, compared, correct = runner.judge(
-                    prog.weights, cell.config, sample, failed, precision)
+                    prog.weights, cell.config, sample, failed, precision,
+                    cell.bench_dir)
                 row[key] = dict(got, compared=compared, correct=correct)
         row["seconds"] = time.time() - t
         print(json.dumps(row), flush=True)

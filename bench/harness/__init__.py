@@ -1,1 +1,1 @@
-"""Shared code of the benchmark: loading, weights, timing, trace reduction, counts."""
+"""Shared code of the benchmark: loading, weights, timing, trace reduction."""

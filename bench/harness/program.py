@@ -1,42 +1,13 @@
 """The system under test: a model and its serve engine, built from a
 configuration file through the program's normal objects (``get_config``,
-``Model``, ``ServeConfig``, ``ServeEngine.generate``)."""
+``Model``, ``ServeConfig``, ``ServeEngine.generate``).  What is particular
+to the model comes from the file's family (``bench/families``)."""
 from __future__ import annotations
 
-import dataclasses
 from typing import Any, Dict, List, Tuple
 
+from . import spec
 from . import weights as weights_mod
-from .counts import Dims
-
-# the published config's keys as the program's ModelConfig names them
-_WIDTHS = {"hidden_size": "d_model", "num_attention_heads": "n_heads",
-           "num_key_value_heads": "n_kv_heads", "intermediate_size": "d_ff",
-           "vocab_size": "vocab_size", "rope_theta": "rope_theta",
-           "qkv_bias": "qkv_bias", "tie_word_embeddings": "tie_embeddings"}
-_ACTS = {"silu": "swiglu", "gelu_pytorch_tanh": "gelu"}
-
-
-def model_config(name: str, cfg: Dict[str, Any]):
-    """The registry's model at this file's depth and norm epsilon; every
-    other field must already equal the file, or this raises."""
-    from repro.configs import get_config
-
-    base = get_config(cfg["program"]["registry"])
-    eps = cfg.get("rms_norm_eps", cfg.get("norm_epsilon"))
-    mcfg = dataclasses.replace(base, name=name,
-                               n_layers=cfg["num_hidden_layers"],
-                               norm_eps=float(eps),
-                               param_dtype=cfg["torch_dtype"],
-                               compute_dtype=cfg["torch_dtype"])
-    want = {field: cfg[key] for key, field in _WIDTHS.items()}
-    want["activation"] = _ACTS[cfg["hidden_act"]]
-    want["superblock"] = ("attn",)
-    got = {k: getattr(mcfg, k) for k in want}
-    if got != want or mcfg.padded_heads != mcfg.n_heads:
-        raise ValueError(f"{name}: the program's {base.name} is not this "
-                         f"file: {got} != {want}")
-    return mcfg
 
 
 def serve_config(cfg: Dict[str, Any]):
@@ -50,17 +21,18 @@ def serve_config(cfg: Dict[str, Any]):
 class Program:
     """Model, weights and engine of one configuration."""
 
-    def __init__(self, name: str, cfg: Dict[str, Any], seed: int):
+    def __init__(self, name: str, cfg: Dict[str, Any], seed: int,
+                 bench_dir: str = spec.BENCH_DIR):
         import jax
 
         from repro.models import Model
 
         self.cfg = cfg
-        self.dims = Dims.from_config(cfg)
-        self.model = Model(model_config(name, cfg))
+        self.family = spec.family(cfg, bench_dir)
+        self.dims = self.family.dims(cfg)
+        self.model = Model(self.family.program_config(name, cfg))
         self.scfg = serve_config(cfg)
-        tree = weights_mod.layout(self.dims, bool(cfg["qkv_bias"]),
-                                  cfg["torch_dtype"])
+        tree = self.family.layout(cfg)
         want = jax.tree_util.tree_map(
             lambda s: (s.shape, s.dtype), self.model.abstract_params())
         have = jax.tree_util.tree_map(
@@ -94,6 +66,12 @@ class Program:
         sflat = treedef.flatten_up_to(specs)
         return jax.tree_util.tree_unflatten(
             treedef, [NamedSharding(m, PartitionSpec(*s)) for s in sflat])
+
+    @property
+    def model_shards(self) -> int:
+        """Chips that share each layer: the mesh's model axis."""
+        mesh = self.cfg.get("mesh")
+        return int(mesh[1]) if mesh else 1
 
     def start(self):
         from repro.serve import ServeEngine

@@ -17,7 +17,6 @@ import numpy as np
 
 from . import spec, stats
 from .clock import CompileClock
-from .counts import Dims
 from .program import Program
 
 WINDOW_SPAN = "bench.window"
@@ -45,11 +44,13 @@ def enable_compile_cache(root: str = spec.ROOT) -> str:
 class Run:
     """What a per-layer metric's reader may read."""
     cell: spec.Cell
-    dims: Dims
+    dims: Any  # the family's dims(cfg)
     chips: int
     peaks: Dict[str, float]
     results: List[Any]  # GenerationResult of each call in the window
     trace: Any = None  # bench.harness.trace.Trace of the traced window
+    family: Any = None  # the family's module: its counts
+    model_shards: int = 1  # chips that share each layer: the mesh's model axis
 
     @property
     def served(self) -> List[Tuple[int, int]]:
@@ -112,15 +113,15 @@ def check_sample(calls, results, n: int, seed: int) -> List[Tuple]:
 
 
 def served_gaps(weights, cfg: Dict[str, Any], sample,
-                precision: str = "float32") -> Dict[str, Any]:
-    """The reference over each sampled prompt with its served tokens: how
-    far each served token's logit lies below the reference's best.  With
-    ``precision="fp8"`` the served tokens are replaced by what the fp8
-    reference puts first (the control)."""
+                precision: str = "float32",
+                bench_dir: str = spec.BENCH_DIR) -> Dict[str, Any]:
+    """The family's reference over each sampled prompt with its served
+    tokens: how far each served token's logit lies below the reference's
+    best.  With ``precision="fp8"`` the served tokens are replaced by what
+    the fp8 reference puts first (the control)."""
     import jax
 
-    from bench.reference import dense_gqa as ref
-
+    ref = spec.reference(cfg, bench_dir)
     rspec = ref.Spec.from_config(cfg)
     widest, n_tok, agree = 0.0, 0, 0
     for prompt, out in sample:
@@ -140,11 +141,12 @@ def served_gaps(weights, cfg: Dict[str, Any], sample,
 
 
 def judge(weights, cfg: Dict[str, Any], sample, failed: int,
-          precision: str = "float32") -> Tuple[Dict[str, Any], Dict, bool]:
+          precision: str = "float32", bench_dir: str = spec.BENCH_DIR
+          ) -> Tuple[Dict[str, Any], Dict, bool]:
     """The check of a run: the reference's gaps over the sample, each
     number compared beside its limit, and whether all hold.  With
     ``precision="fp8"`` the control stands in the program's place."""
-    got = served_gaps(weights, cfg, sample, precision)
+    got = served_gaps(weights, cfg, sample, precision, bench_dir)
     limit = cfg["limits"]["widest_logit_gap"]
     compared = {"widest_logit_gap": {"value": got["widest_logit_gap"],
                                      "limit": limit},
@@ -186,10 +188,9 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     if compile_cache:
         log("compile_cache:", enable_compile_cache())
     clock = CompileClock()
-    dims = Dims.from_config(cell.config)
-    gen = spec.traffic_generator(cell.traffic)
-    mix = gen.make(cell.traffic, dims.vocab, seed)
-    prog = Program(cell.config_name, cell.config, seed)
+    prog = Program(cell.config_name, cell.config, seed, cell.bench_dir)
+    mix = spec.traffic_generator(cell.traffic).make(cell.traffic,
+                                                    prog.dims.vocab, seed)
     prog.start()
     if program_hook is not None:
         program_hook(prog)
@@ -213,9 +214,10 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     log(f"calls: {len(calls)}")
     log(f"compiles_in_window: {late}")
     log(f"requests: {attempted} failed: {failed}")
-    run = Run(cell=cell, dims=dims, chips=cell.chips,
+    run = Run(cell=cell, dims=prog.dims, chips=cell.chips,
               peaks=peaks_for(devices[0].device_kind) if trace else {},
-              results=results, trace=tr)
+              results=results, trace=tr, family=prog.family,
+              model_shards=prog.model_shards)
     if trace:
         metrics = read_per_layer(cell, run)
     else:
@@ -230,7 +232,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
                           seed)
     t = time.perf_counter()
     got, compared, correct = judge(prog.weights, cell.config, sample,
-                                   failed, control or "float32")
+                                   failed, control or "float32",
+                                   cell.bench_dir)
     log(f"reference_s: {time.perf_counter() - t} over {got['tokens']} "
         f"served tokens of {len(sample)} requests; argmax agrees on "
         f"{got['argmax_agree']}")

@@ -1,49 +1,16 @@
 """Random weights drawn on the device, in one jitted call, from the seed.
 
-The tree is laid out as the program takes it: leaves stacked over
-layers under ``blocks/0_attn``, matmul weights in the type they are served in
-(``torch_dtype``), norm scales in float32.  The reference reads the same
-tree; nothing in it is made by the program.  Scales keep activations
-near unit size at any width: matmul weights ``N(0, 1/fan_in)``, norm
-scales ``1 + N(0, 0.05^2)``, biases ``N(0, 0.1^2)``, and the embedding
-``N(0, 1/d)`` (the program multiplies it by ``sqrt(d)``).
+The tree is the configuration's family's ``layout(cfg)``
+(``bench/families/<family>.py``): each leaf ``(shape, dtype, kind,
+std)``, laid out as the program takes it.  A ``normal`` leaf is drawn
+``N(0, std^2)``, a ``norm`` leaf ``1 + N(0, std^2)``.  The reference
+reads the same tree; nothing in it is made by the program.
 """
 from __future__ import annotations
 
-import math
 from typing import Any, Dict
 
 import numpy as np
-
-from .counts import Dims
-
-def layout(dims: Dims, qkv_bias: bool,
-           dtype: str = "bfloat16") -> Dict[str, Any]:
-    L, d, H, KV, Dh, F, V = (dims.layers, dims.d, dims.heads, dims.kv_heads,
-                             dims.head_dim, dims.ff, dims.vocab)
-    bf = dtype
-    attn = {
-        "wq": ((L, d, H, Dh), bf, "normal", 1 / math.sqrt(d)),
-        "wk": ((L, d, KV, Dh), bf, "normal", 1 / math.sqrt(d)),
-        "wv": ((L, d, KV, Dh), bf, "normal", 1 / math.sqrt(d)),
-        "wo": ((L, H, Dh, d), bf, "normal", 1 / math.sqrt(H * Dh)),
-    }
-    if qkv_bias:
-        attn["bq"] = ((L, H, Dh), bf, "normal", 0.1)
-        attn["bk"] = ((L, KV, Dh), bf, "normal", 0.1)
-        attn["bv"] = ((L, KV, Dh), bf, "normal", 0.1)
-    mlp = {"wi": ((L, d, F), bf, "normal", 1 / math.sqrt(d)),
-           "wo": ((L, F, d), bf, "normal", 1 / math.sqrt(F))}
-    if dims.gated:
-        mlp["wg"] = ((L, d, F), bf, "normal", 1 / math.sqrt(d))
-    norm = ((L, d), "float32", "norm", 0.05)
-    return {
-        "embed": ((V, d), bf, "normal", 1 / math.sqrt(d)),
-        "blocks": {"0_attn": {"ln1": norm, "attn": attn, "ln2": norm,
-                              "mlp": mlp}},
-        "final_norm": ((d,), "float32", "norm", 0.05),
-        "lm_head": ((d, V), bf, "normal", 1 / math.sqrt(d)),
-    }
 
 
 def _is_leaf(x) -> bool:

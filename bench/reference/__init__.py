@@ -1,1 +1,2 @@
-"""Plain float32 reference of the dense GQA decoder, independent of the program."""
+"""Plain float32 reference of each model family (one file each, named as
+the family), independent of the program."""

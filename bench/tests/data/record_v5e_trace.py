@@ -73,7 +73,8 @@ def main(argv=None) -> int:
         summary["planes"][plane.name] = lines
     run = runner.Run(cell=cell, dims=prog.dims, chips=1,
                      peaks=peaks_for(jax.devices()[0].device_kind),
-                     results=results, trace=tr)
+                     results=results, trace=tr, family=prog.family,
+                     model_shards=prog.model_shards)
     summary["metrics"] = {m["name"]: spec.metric_reader(m["name"])(run)
                           for m in cell.per_layer}
     summary["breakdown"] = tr.breakdown()

@@ -1,7 +1,9 @@
-"""FLOP and byte counts against hand counts at a small shape; peaks."""
+"""FLOP and byte counts of the dense GQA family against hand counts at a
+small shape, per chip under a mesh; peaks."""
 import pytest
 
-from bench.harness import counts, peaks
+from bench.families import dense_gqa as counts
+from bench.harness import peaks
 
 DIMS = counts.Dims(d=8, heads=4, kv_heads=2, head_dim=2, ff=16, vocab=32,
                    layers=3, gated=True)
@@ -33,13 +35,13 @@ def test_one_decode_token_kv_bytes_by_hand():
     # prompt 7, 2 new tokens: one decode step, feeding position 7 and
     # attending 8 keys: K and V of 8 tokens x 2 KV heads x 2 dims x 2 B,
     # q in and out 4 heads x 2 dims x 2 B each, per layer
-    nbytes, flops = counts.paged_kernel_work(DIMS, 7, 2)
+    nbytes, flops = counts.paged_kernel_work(DIMS, 7, 2, 1)
     assert nbytes == 3 * (2 * 8 * 2 * 2 * 2 + 2 * 4 * 2 * 2)
     assert flops == 3 * 4 * 4 * 2 * 8
 
 
 def test_first_token_needs_no_decode():
-    assert counts.paged_kernel_work(DIMS, 7, 1) == (0, 0)
+    assert counts.paged_kernel_work(DIMS, 7, 1, 1) == (0, 0)
     assert counts.decode_flops(DIMS, 7, 1) == 0
 
 
@@ -50,6 +52,27 @@ def test_published_qwen_width_parameter_count():
                     vocab=152064, layers=8, gated=True)
     n = d.layers * d.layer_matmul_params + 2 * d.vocab * d.d
     assert n == pytest.approx(5.458e9, rel=1e-3)
+
+
+QWEN = counts.Dims(d=5120, heads=40, kv_heads=8, head_dim=128, ff=27648,
+                   vocab=152064, layers=32, gated=True)
+
+
+@pytest.mark.parametrize("prompt,new", [(1024, 129), (64, 16), (3584, 512)])
+def test_four_shards_count_a_quarter_of_the_kernel_work(prompt, new):
+    """Under TP (1, 4) each chip's kernel streams 2 of the 8 KV heads and
+    their 10 q heads: exactly a quarter of the bytes and operations."""
+    whole = counts.paged_kernel_work(QWEN, prompt, new, 1)
+    shard = counts.paged_kernel_work(QWEN, prompt, new, 4)
+    assert shard[0] * 4 == whole[0] and shard[1] * 4 == whole[1]
+    n = new - 1
+    ctx = counts.sum_contexts(prompt + 1, prompt + n)
+    assert shard[0] == 32 * (2 * 2 * 128 * 2 * ctx + 2 * 10 * 128 * 2 * n)
+
+
+def test_heads_that_do_not_split_are_refused():
+    with pytest.raises(ValueError):
+        counts.paged_kernel_work(QWEN, 64, 16, 3)
 
 
 def test_v5e_peaks_and_unknown_device():

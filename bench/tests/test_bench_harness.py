@@ -3,18 +3,22 @@ shape the benchmark's contract gives it."""
 import json
 import os
 import re
+import time
 
 import pytest
 
 import tiny
-from bench.harness import spec
+from bench.harness import runner, spec
 
 BENCH = spec.load_json(os.path.join(spec.ROOT, "BENCHMARK.json"))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 
 
 def test_a_new_config_mix_kind_and_metric_need_only_new_files(tmp_path):
-    """Everything a later cell brings is a file under a new name."""
+    """Everything a later cell brings is a file under a new name, a model
+    family too: the test family ``moe_gqa`` (a family module and a
+    reference under ``data/``) runs a whole cell through the harness and
+    comes out correct."""
     d = tiny.write_bench_dir(tmp_path, {"dummy-config": tiny.config()},
                              {"dummy-mix": dict(tiny.TRAFFIC,
                                                 kind="dummy_kind")})
@@ -34,6 +38,18 @@ def test_a_new_config_mix_kind_and_metric_need_only_new_files(tmp_path):
         cell.traffic, 7, 1) == ("made", 6, 7, 1)
     assert "dummy_metric" in [m["name"] for m in cell.per_layer]
     assert spec.metric_reader("dummy_metric", d)(None) == 42.0
+
+    moe = str(tmp_path / "moe")
+    tiny.write_bench_dir(moe, {"moe-config": tiny.moe_config()},
+                         {"mix": tiny.TRAFFIC}, families=("moe_gqa",),
+                         family_dir=tiny.DATA)
+    cell = spec.load_cell("moe.cell", moe,
+                          tiny.benchmark([("moe.cell", "moe-config", "mix")]))
+    assert spec.family(cell.config, moe).__file__.startswith(moe)
+    out = runner.run_cell(cell, 2 ** 31 + 13, 0.0, False, time.time(),
+                          compile_cache=False)
+    assert out["correct"] is True and out["failed"] == 0
+    assert out["compared"]["widest_logit_gap"]["value"] <= 1e-3
 
 
 def test_metrics_apply_only_to_their_cells():
@@ -105,16 +121,17 @@ def test_config_files_are_the_programs_models(name, params):
     benchmark's weight layout is the program's parameter tree."""
     import jax
 
-    from bench.harness import counts, program, weights
+    from bench.harness import weights
     from repro.models import Model
 
     cfg = spec.load_json(os.path.join(spec.BENCH_DIR, "configs",
                                       name + ".json"))
-    mcfg = program.model_config(name, cfg)
+    family = spec.family(cfg)
+    assert family.__file__ == os.path.join(spec.BENCH_DIR, "families",
+                                           "dense_gqa.py")
+    mcfg = family.program_config(name, cfg)
     assert mcfg.n_layers == cfg["num_hidden_layers"]
-    tree = weights.layout(counts.Dims.from_config(cfg), cfg["qkv_bias"],
-                          cfg["torch_dtype"])
-    have = weights.shapes(tree)
+    have = weights.shapes(family.layout(cfg))
     want = Model(mcfg).abstract_params()
     assert jax.tree_util.tree_map(lambda s: (s.shape, s.dtype), have) == \
         jax.tree_util.tree_map(lambda s: (s.shape, s.dtype), want)
@@ -125,10 +142,8 @@ def test_config_files_are_the_programs_models(name, params):
 
 
 def test_a_file_that_is_not_the_program_is_refused():
-    from bench.harness import program
-
     cfg = dict(spec.load_json(os.path.join(
         spec.BENCH_DIR, "configs", "qwen2.5-32b-8of64.json")),
         intermediate_size=1000)
     with pytest.raises(ValueError):
-        program.model_config("x", cfg)
+        spec.family(cfg).program_config("x", cfg)

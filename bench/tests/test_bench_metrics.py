@@ -3,7 +3,8 @@ from types import SimpleNamespace
 
 import pytest
 
-from bench.harness import counts, spec
+from bench.families import dense_gqa as counts
+from bench.harness import spec
 from bench.harness.runner import Run
 from bench.harness.trace import Trace
 
@@ -40,9 +41,10 @@ RESULTS = [SimpleNamespace(steps=2, per_request=[
     {"prompt_len": 7, "new_tokens": 2, "ttft_s": 0.1, "latency_s": 0.2}])]
 
 
-def _run(trace=TRACE, chips=2):
+def _run(trace=TRACE, chips=2, model_shards=1):
     return Run(cell=None, dims=DIMS, chips=chips, peaks=PEAKS,
-               results=RESULTS, trace=Trace(trace))
+               results=RESULTS, trace=Trace(trace), family=counts,
+               model_shards=model_shards)
 
 
 def read(name, run=None):
@@ -63,11 +65,23 @@ def test_prefill_ms_per_ktok():
 
 
 def test_paged_attn_roofline():
-    b1, f1 = counts.paged_kernel_work(DIMS, 5, 3)
-    b2, f2 = counts.paged_kernel_work(DIMS, 7, 2)
+    b1, f1 = counts.paged_kernel_work(DIMS, 5, 3, 1)
+    b2, f2 = counts.paged_kernel_work(DIMS, 7, 2, 1)
     least = max((b1 + b2) / 1e9, (f1 + f2) / 1e12)
     assert read("paged_attn_roofline") == pytest.approx(
         100 * least / 70e-9)
+
+
+def test_paged_attn_roofline_counts_chip_0s_shard():
+    """Under a (1, 2) mesh chip 0's kernel streams half the heads, so the
+    same kernel time reads half the share."""
+    whole = read("paged_attn_roofline")
+    assert read("paged_attn_roofline", _run(model_shards=2)) == \
+        pytest.approx(whole / 2)
+
+
+def test_mfu_counts_the_whole_model_under_a_mesh():
+    assert read("mfu", _run(model_shards=2)) == read("mfu")
 
 
 def test_device_idle_share():

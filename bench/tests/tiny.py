@@ -1,10 +1,13 @@
 """A cell at test size: the program's dense GQA block at tiny widths in
-float32, registered under its own name, with a small closed mix."""
+float32, registered under its own name, with a small closed mix.  Also
+the test family ``moe_gqa`` (``data/families``, ``data/reference``) at
+tiny widths."""
 import copy
 import json
 import os
+import shutil
 
-from repro.configs import ModelConfig, get_config, register
+from repro.configs import ModelConfig, MoESpec, get_config, register
 
 from bench.harness import spec
 
@@ -36,7 +39,7 @@ def config(gated: bool = True, mesh=None) -> dict:
     name = "bench-tiny-" + ("swiglu" if gated else "gelu") + (
         "-tp" if mesh else "")
     _register(name, gated, widths)
-    cfg = dict(widths, num_hidden_layers=2,
+    cfg = dict(widths, family="dense_gqa", num_hidden_layers=2,
                hidden_act="silu" if gated else "gelu_pytorch_tanh",
                rope_theta=10000.0, rms_norm_eps=1e-6,
                tie_word_embeddings=False, torch_dtype="float32",
@@ -47,6 +50,31 @@ def config(gated: bool = True, mesh=None) -> dict:
                       "kv_layout": "paged", "schedule": "fifo",
                       "page_policy": "reserve", "temperature": 0.0},
                mesh=mesh, limits={"widest_logit_gap": 1e-3})
+    return cfg
+
+
+DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
+
+
+def moe_config() -> dict:
+    """The test family ``moe_gqa``: 4 experts, 2 a token, with a router
+    capacity that drops no token."""
+    name = "bench-tiny-moe"
+    try:
+        get_config(name)
+    except KeyError:
+        register(ModelConfig(
+            name=name, family="moe", n_layers=2, d_model=64, n_heads=4,
+            n_kv_heads=2, d_ff=96, vocab_size=512, superblock=("moe",),
+            moe=MoESpec(n_experts=4, experts_per_token=2, d_ff=96,
+                        capacity_factor=2.0),
+            activation="swiglu", rope_theta=10_000.0, tie_embeddings=False,
+            param_dtype="float32", compute_dtype="float32",
+            vocab_pad_multiple=64))
+    cfg = config(True)
+    cfg.update(family="moe_gqa", qkv_bias=False, num_local_experts=4,
+               num_experts_per_tok=2,
+               program={"registry": name, "embed_scale": True})
     return cfg
 
 
@@ -66,13 +94,21 @@ def benchmark(cells) -> dict:
                            "why": "test"} for n, c, t in cells]}
 
 
-def write_bench_dir(tmp, configs: dict, mixes: dict) -> str:
-    """A bench tree under ``tmp`` holding only these configs and mixes."""
+def write_bench_dir(tmp, configs: dict, mixes: dict,
+                    families=("dense_gqa",), family_dir=spec.BENCH_DIR
+                    ) -> str:
+    """A bench tree under ``tmp`` holding only these configs and mixes,
+    and the files of these model families, copied from ``family_dir``."""
     for sub, files in (("configs", configs), ("traffic", mixes)):
         os.makedirs(os.path.join(tmp, sub), exist_ok=True)
         for name, body in files.items():
             with open(os.path.join(tmp, sub, name + ".json"), "w") as f:
                 json.dump(body, f)
+    for fam in families:
+        for sub in ("families", "reference"):
+            os.makedirs(os.path.join(tmp, sub), exist_ok=True)
+            shutil.copy(os.path.join(family_dir, sub, fam + ".py"),
+                        os.path.join(tmp, sub))
     return str(tmp)
 
 
